@@ -17,9 +17,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -96,7 +93,7 @@ def decode_attention_pallas(q, k, v, lengths, *, kv_block: int = 2048,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, kt, vt)

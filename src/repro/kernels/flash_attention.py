@@ -21,9 +21,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -136,7 +133,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)

@@ -11,6 +11,7 @@ same asymptotic memory behaviour the TPU kernels deliver.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -45,13 +46,37 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
     causal attention; toggleable for the perf study).
     """
     if _pick(backend) == "pallas":
-        from repro.kernels.flash_attention import flash_attention_pallas
-
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_block=q_block, kv_block=kv_block,
-                                      interpret=interpret)
+        return _flash_pallas(q, k, v, causal, window, q_block, kv_block,
+                             interpret)
     return _flash_jnp(q, k, v, causal=causal, window=window,
                       q_block=q_block, kv_block=kv_block, causal_skip=causal_skip)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_pallas(q, k, v, causal, window, q_block, kv_block, interpret):
+    from repro.kernels.flash_attention import flash_attention_pallas
+
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  q_block=q_block, kv_block=kv_block,
+                                  interpret=interpret)
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, q_block, kv_block, interpret):
+    out = _flash_pallas(q, k, v, causal, window, q_block, kv_block, interpret)
+    return out, (q, k, v)
+
+
+def _flash_pallas_bwd(causal, window, q_block, kv_block, interpret, res, g):
+    # the kernel has no backward pass: differentiate its chunked jnp twin
+    # (same masked online softmax), recomputing the forward from q/k/v
+    def twin(q, k, v):
+        return _flash_jnp(q, k, v, causal=causal, window=window,
+                          q_block=q_block, kv_block=kv_block, causal_skip=True)
+
+    return jax.vjp(twin, *res)[1](g)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 def _flash_jnp(q, k, v, *, causal, window, q_block, kv_block, causal_skip):

@@ -33,9 +33,6 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
             acc_scr, *, page_size: int, n_tab: int, n_pool: int,
@@ -117,7 +114,7 @@ def paged_attention_pallas(q, k_pool, v_pool, table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hk, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt)

@@ -16,9 +16,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, a_ref, h0_ref, o_ref, hT_ref, h_scr, *, chunk: int, n_chunks: int):
     c = pl.program_id(2)
@@ -83,7 +80,7 @@ def rglru_pallas(x, a_log, state=None, *, chunk: int = 256, w_block: int = 512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, w_block), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a_log, state)

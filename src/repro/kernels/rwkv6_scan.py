@@ -24,9 +24,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _CLAMP = 30.0
 
 
@@ -111,7 +108,7 @@ def wkv6_pallas(r, k, v, w, u, state=None, *, chunk: int = 32, interpret: bool =
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, wt, u, state)

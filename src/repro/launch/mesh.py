@@ -11,19 +11,13 @@ pods.
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax ≥ 0.5 takes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto implicitly
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

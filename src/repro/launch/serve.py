@@ -1,9 +1,12 @@
 """Serving launcher: RoBatch's data plane as a CLI, in two modes.
 
-``engine`` — bring up one continuous-batching engine for an architecture and
-serve a batched-prompt workload (the original single-model path)::
+``engine`` — bring up one continuous-batching engine for an architecture at
+its published widths (``--reduced`` serves its smoke config instead), with
+random weights from ``--seed``, and serve a batched-prompt workload (the
+original single-model path; ``chip_smoke.py`` drives it on the chip)::
 
     PYTHONPATH=src python -m repro.launch.serve engine --arch tiny-m --requests 12
+    python -m repro.launch.serve engine --arch qwen1.5-0.5b --batch-prompt 4
 
 ``online`` — the full online serving layer: build the pool a spec describes,
 fit the modeling stage once, then stream a Poisson arrival workload through
@@ -59,11 +62,28 @@ pre-spec flags (``--task``/``--family``/``--n-train``/``--coreset``/
 ``--seed``) keep working as a deprecation shim that overrides the spec.
 """
 import argparse
+import os
 import sys
 import time
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
 
-def engine_main(argv):
+
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at a fixed path, so a second
+    process on the same checkout reuses what the first compiled.  A
+    ``JAX_COMPILATION_CACHE_DIR`` from the environment wins (JAX reads it
+    itself); otherwise the cache lives in ``<repo>/.jax_cache``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(REPO_ROOT, ".jax_cache"))
+
+
+def engine_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="serve engine")
     ap.add_argument("--arch", default="tiny-s")
     ap.add_argument("--requests", type=int, default=8)
@@ -80,7 +100,10 @@ def engine_main(argv):
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page in the paged layout")
     ap.add_argument("--reduced", action="store_true",
-                    help="serve the reduced (smoke) config of a big arch")
+                    help="serve the reduced (smoke) config of the arch "
+                         "instead of its published widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and the prompts")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="sampling temperature (0 = greedy, the default)")
     ap.add_argument("--top-k", type=int, default=0,
@@ -95,8 +118,36 @@ def engine_main(argv):
                          "decoding (e.g. tiny-s drafting for tiny-m)")
     ap.add_argument("--spec-k", type=int, default=4,
                     help="speculation depth with --draft-member")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def engine_config(args, device_bytes=None):
+    """The architecture ``serve engine`` builds: the registered one at its
+    published widths, or its smoke config with ``--reduced``.  What the
+    engine cannot serve as asked fails here with a message, never with a
+    silent cut.  ``device_bytes`` is the device memory the weights must fit
+    in (``None`` when the backend does not report it)."""
+    from repro.config import get_arch
+
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if cfg.vocab_size < 259 or cfg.enc_dec or cfg.frontend:
+        raise SystemExit(f"{cfg.name}: byte-tokenizer text serving needs a plain "
+                         f"decoder with vocab ≥ 259")
+    weight_bytes = cfg.param_count() * (2 if cfg.dtype == "bfloat16" else 4)
+    if device_bytes and weight_bytes > device_bytes:
+        raise SystemExit(f"{cfg.name}: {weight_bytes / 2**30:.1f} GiB of weights "
+                         f"do not fit the device's {device_bytes / 2**30:.1f} GiB; "
+                         f"pass --reduced to serve its smoke config")
+    return cfg
+
+
+def build_engine(args):
+    """Everything ``serve engine`` serves, from its parsed flags: the model
+    with random weights from ``--seed``, its engine, and ``--requests``
+    requests (batch prompts of ``--batch-prompt`` queries under one system
+    prompt when that is set).  Returns ``(cfg, engine, requests)``."""
     import jax
     import numpy as np
 
@@ -106,14 +157,10 @@ def engine_main(argv):
     from repro.serving.engine import Request, ServingEngine
     from repro.serving.generation import GenerationConfig
 
-    cfg = get_arch(args.arch)
-    if args.reduced or cfg.param_count() > 5e7:
-        cfg = cfg.reduced()
-    if cfg.vocab_size < 259 or cfg.enc_dec or cfg.frontend:
-        raise SystemExit(f"{cfg.name}: byte-tokenizer text serving needs a plain "
-                         f"decoder with vocab ≥ 259 (use tiny-s/m/l or --reduced dense archs)")
+    mem = jax.devices()[0].memory_stats() or {}
+    cfg = engine_config(args, mem.get("bytes_limit"))
     model = Model(cfg, ShardingConfig(remat="none"))
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(args.seed))
     paged = not args.contiguous
     if paged and (cfg.enc_dec or any(k != "attn" for k in cfg.layer_kinds())):
         print(f"{cfg.name}: paged KV needs a decoder-only global-attention "
@@ -127,7 +174,7 @@ def engine_main(argv):
             raise SystemExit("--draft-member needs the paged KV layout "
                              "(drop --contiguous)")
         dmodel = Model(dcfg, ShardingConfig(remat="none"))
-        dparams = dmodel.init(jax.random.PRNGKey(0))
+        dparams = dmodel.init(jax.random.PRNGKey(args.seed))
         engine = SpeculativeEngine(model, params, dmodel, dparams,
                                    max_slots=args.slots, max_len=args.max_len,
                                    spec_k=args.spec_k,
@@ -145,7 +192,7 @@ def engine_main(argv):
                                temperature=args.temperature,
                                top_k=args.top_k, top_p=args.top_p,
                                seed=args.gen_seed)
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     prompts = []
     for i in range(args.requests):
         qs = [f"{rng.integers(0, 99)}+{rng.integers(0, 99)}"
@@ -153,15 +200,18 @@ def engine_main(argv):
         prompts.append(fmt.format(qs) if args.batch_prompt else fmt.tokenizer.encode(qs[0]))
     reqs = [Request(rid=i, tokens=p, max_new=args.max_new, gen=gen)
             for i, p in enumerate(prompts)]
+    return cfg, engine, reqs
 
+
+def engine_main(argv):
+    cfg, engine, reqs = build_engine(engine_parser().parse_args(argv))
     t0 = time.time()
     engine.serve(reqs)
     dt = time.time() - t0
-    tok = fmt.tokenizer
     done = sum(r.done for r in reqs)
     out_toks = sum(len(r.out_tokens) for r in reqs)
     print(f"{cfg.name}: served {done}/{len(reqs)} requests "
-          f"({out_toks} tokens) in {dt:.1f}s via {args.slots} slots")
+          f"({out_toks} tokens) in {dt:.1f}s via {engine.max_slots} slots")
     occ = engine.kv_occupancy()
     if occ.get("paged"):
         print(f"  kv pages: {occ['pages_used']}/{occ['n_pages']} live "
@@ -172,7 +222,7 @@ def engine_main(argv):
               f"accept={engine.accept_rate():.2f} bonus={engine.n_bonus}")
     for r in reqs[:3]:
         print(f"  req{r.rid}: prompt {len(r.tokens)} toks -> "
-              f"{tok.decode(r.out_tokens)[:48]!r}")
+              f"{engine.tok.decode(r.out_tokens)[:48]!r}")
 
 
 def _add_robust_flags(ap):
@@ -543,6 +593,7 @@ def http_main(argv):
 
 
 def main():
+    enable_compile_cache()
     argv = sys.argv[1:]
     if argv and argv[0] in ("engine", "online", "http"):
         mode, rest = argv[0], argv[1:]

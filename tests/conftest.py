@@ -5,24 +5,15 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import pytest
+from hypothesis import settings
+
+from repro.data import make_simulated_pool, make_workload
 
 # Hypothesis profile pinned for CI stability: no per-example deadline (hosted
 # runners stall unpredictably under load — deadline flakes are pure noise)
-# and derandomized so a red property test reproduces from the log.  CI sets
-# REQUIRE_HYPOTHESIS=1, making a missing/broken hypothesis install a hard
-# error instead of a silent skip of every property test (seed defect: four
-# whole modules used to importorskip away).
-try:
-    from hypothesis import settings as _hyp_settings
-    _hyp_settings.register_profile("ci", deadline=None, derandomize=True)
-    _hyp_settings.load_profile("ci")
-except ImportError:
-    if os.environ.get("REQUIRE_HYPOTHESIS"):
-        raise RuntimeError(
-            "REQUIRE_HYPOTHESIS=1 but hypothesis is not importable — "
-            "property tests would silently skip; fix the CI install")
-
-from repro.data import make_simulated_pool, make_workload
+# and derandomized so a red property test reproduces from the log.
+settings.register_profile("ci", deadline=None, derandomize=True)
+settings.load_profile("ci")
 
 
 @pytest.fixture(scope="session")

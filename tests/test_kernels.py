@@ -1,10 +1,12 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp oracles
 (interpret=True executes kernel bodies on CPU), plus the ops-layer chunked
 fallbacks against the same oracles, plus hypothesis property sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypcompat import given, settings, st   # property tests skip w/o hypothesis
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
@@ -65,6 +67,25 @@ def test_ops_flash_matches_oracle_uneven_and_offset():
     want = ref.mha_ref(q, k, v, causal=True)
     got = ops.flash_attention(q, k, v, causal=True, q_block=8, kv_block=8, backend="jnp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal,win", [(True, None), (True, 8), (False, None)])
+def test_ops_flash_pallas_gradient_matches_oracle(causal, win):
+    """Training through the Pallas path (the tiny pool trains on the chip):
+    its gradient is the oracle's gradient."""
+    q, k, v = arr(2, 32, 4, 16), arr(2, 32, 2, 16), arr(2, 32, 2, 16)
+    w = arr(2, 32, 4, 16)
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v) * w).sum()
+
+    got = jax.grad(loss(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=causal, window=win, q_block=16, kv_block=16,
+        backend="pallas", interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref.mha_ref(
+        q, k, v, causal=causal, window=win)), argnums=(0, 1, 2))(q, k, v)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_), atol=1e-4, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -6,13 +6,13 @@ after every step — refcounts equal table references, pages are never both
 free and referenced, a fork never aliases, pages-in-use never exceeds the
 pool, and freeing a retired slot returns exactly its non-shared pages.
 
-Example-based twins of each property run without hypothesis (hypcompat skips
-only the ``@given`` tests), so the allocator keeps real coverage even where
-hypothesis is absent.
+Example-based twins of each property pin the same invariants on fixed
+sequences.
 """
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.serving.kvpool import BlockAllocator, OutOfPages, PagedCacheManager
 

@@ -9,7 +9,8 @@ import itertools
 
 import numpy as np
 import pytest
-from hypcompat import given, settings, st   # property tests skip w/o hypothesis
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.pareto import CandidateSpace
 from repro.core.problem import State

@@ -2,7 +2,8 @@
 (Thm. 5.3), greedy vs brute-force, budget monotonicity (hypothesis-driven)."""
 import numpy as np
 import pytest
-from hypcompat import given, settings, st   # property tests skip w/o hypothesis
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.pareto import CandidateSpace, pareto_frontier
 from repro.core.problem import State
