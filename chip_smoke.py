@@ -219,7 +219,7 @@ def phase_engine(dev, clock: CompileClock, seed: int) -> Checks:
           f"serve_wall_s={wall_s:.3f} run_s={run_s:.3f} requests={len(reqs)} "
           f"tokens_served={tokens} prefill_calls={engine.n_prefill_calls} "
           f"decode_calls={engine.n_decode_calls} pages_peak={occ['peak_pages']}/"
-          f"{occ['n_pages']} prefix_shares={occ['prefix_shares']} "
+          f"{occ['pages_reserved']} prefix_shares={occ['prefix_shares']} "
           f"cow_forks={occ['cow_forks']} persistent_cache_hits={clock.cache_hits} "
           f"{memory_line(dev)}", flush=True)
     print(f"engine: smoke figure, not a metric: {tokens / run_s:.1f} tokens/s "

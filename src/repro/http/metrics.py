@@ -10,8 +10,9 @@ a dozen lines of string assembly, and ``requirements-ci.txt`` stays lean.
 :class:`repro.serving.online.OnlineRobatchServer` through its ``on_window`` /
 ``on_complete`` hooks, translating the serving plane's existing signals —
 window accounting, per-member capacity pressure, breaker transitions, replica
-counts and pending async builds, paged-KV occupancy, budget spend — into
-scrapeable families.  The HTTP front-end (:mod:`repro.http.server`) adds its
+counts and pending async builds, paged-KV occupancy and reservation, engine
+dispatches, compiles, queue waits by stage, budget spend — into scrapeable
+families.  The HTTP front-end (:mod:`repro.http.server`) adds its
 own request/latency families on top and serves ``registry.render()`` at
 ``GET /metrics``.
 """
@@ -20,6 +21,8 @@ from __future__ import annotations
 import threading
 from bisect import bisect_left
 from typing import Optional, Sequence
+
+from repro.serving import tracing
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "bind_server_metrics", "DEFAULT_BUCKETS"]
@@ -276,8 +279,20 @@ def bind_server_metrics(registry: MetricsRegistry, server,
         ("member", "kind"))
     cache_entries = registry.gauge(f"{prefix}_cache_entries",
                                    "response cache live entries")
-    cache_hits = registry.gauge(f"{prefix}_cache_hits_total",
-                                "response cache hits")
+    cache_hits = registry.counter(f"{prefix}_cache_hits_total",
+                                  "response cache hits")
+    queue_wait = registry.histogram(
+        f"{prefix}_queue_wait_seconds",
+        "wait of a served request by stage: window (arrival to the round "
+        "that dispatched it) and member (that round to its member starting)",
+        ("stage",))
+    engine_calls = registry.counter(
+        f"{prefix}_engine_calls_total",
+        "engine dispatches by member and program (decode, prefill)",
+        ("member", "program"))
+    compiles = registry.counter(
+        f"{prefix}_compiles_total", "backend compiles in this process by program",
+        ("program",))
     sem_events = registry.counter(
         f"{prefix}_semcache_events_total",
         "semantic cache events (hit/miss/insert/evict/expire)", ("event",))
@@ -304,9 +319,14 @@ def bind_server_metrics(registry: MetricsRegistry, server,
         breaker_trips.labels(member=name)     # zero-valued child
     trips_seen = [br.n_trips for br in server.breakers]
 
-    # semantic-cache counters are cumulative on the cache object — scrape
+    # cache and engine counters are cumulative on their objects — scrape
     # deltas per window so a re-bound registry never double-counts
-    sem_seen = {"hit": 0, "miss": 0, "insert": 0, "evict": 0, "expire": 0}
+    seen: dict = {}
+
+    def inc_to(child, key, total) -> None:
+        if total > seen.get(key, 0):
+            child.inc(total - seen.get(key, 0))
+            seen[key] = total
 
     def on_complete(req) -> None:
         if req.dropped:
@@ -316,6 +336,12 @@ def bind_server_metrics(registry: MetricsRegistry, server,
                        else "cache_hit" if req.cache_hit else "served")
             completions.labels(outcome=outcome).inc()
             latency.observe(max(0.0, req.latency))
+            if req.admitted_at is not None:
+                queue_wait.labels(stage="window").observe(
+                    max(0.0, req.admitted_at - req.arrived_at))
+                if req.served_at is not None:
+                    queue_wait.labels(stage="member").observe(
+                        max(0.0, req.served_at - req.admitted_at))
             utility.inc(float(req.utility or 0.0))
             if req.sem_hit:
                 sem_sim.observe(req.sem_sim)
@@ -328,7 +354,7 @@ def bind_server_metrics(registry: MetricsRegistry, server,
         late.set(rep.late_s)
         spent_g.set(server.bucket.total_spent)
         cache_entries.set(len(server.cache))
-        cache_hits.set(server.cache.hits)
+        inc_to(cache_hits.labels(), "cache_hits", server.cache.hits)
         sc = getattr(server, "semcache", None)
         if sc is not None:
             sem_entries.set(len(sc))
@@ -338,9 +364,7 @@ def bind_server_metrics(registry: MetricsRegistry, server,
                                  ("insert", sc.insertions),
                                  ("evict", sc.evictions),
                                  ("expire", sc.expirations)):
-                if total > sem_seen[event]:
-                    sem_events.labels(event=event).inc(total - sem_seen[event])
-                    sem_seen[event] = total
+                inc_to(sem_events.labels(event=event), ("sem", event), total)
         for event, n in (("admitted", rep.n_admitted),
                          ("deferred", rep.n_deferred),
                          ("shed", rep.n_shed), ("failed", rep.n_failed),
@@ -379,6 +403,19 @@ def bind_server_metrics(registry: MetricsRegistry, server,
             kv_pages.labels(member=names[k], kind="used").set(used)
             kv_pages.labels(member=names[k], kind="shared").set(shared)
             kv_pages.labels(member=names[k], kind="cow_forks").set(forks)
+        for k, counts in rep.engine_counts:
+            if counts["pages_reserved"]:
+                kv_pages.labels(member=names[k], kind="reserved").set(
+                    counts["pages_reserved"])
+            for program in ("decode", "prefill"):
+                inc_to(engine_calls.labels(member=names[k], program=program),
+                       (k, program), counts[f"{program}_calls"])
+        # compiles are counted process-wide: raise each child to the total,
+        # so any number of bound servers count each compile once
+        for program, n in tracing.compile_counts().items():
+            child = compiles.labels(program=program)
+            if n > child.value:
+                child.inc(n - child.value)
 
     def chain(old, new):
         if old is None:

@@ -37,6 +37,7 @@ from repro.http.protocol import (SSE_DONE, ApiError, chunk_frame,
                                  completion_response, finish_frame,
                                  models_response, parse_chat_body,
                                  resolve_query_idx, role_frame, sse_event)
+from repro.serving import tracing
 from repro.serving.fault import CircuitState
 
 __all__ = ["HttpFrontend"]
@@ -120,30 +121,32 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json(404, ApiError(404, f"no route {path}").body(), path)
             return
         t0 = time.perf_counter()
-        try:
-            length = int(self.headers.get("Content-Length") or 0)
-            parsed = parse_chat_body(self.rfile.read(length))
-            q = resolve_query_idx(parsed, fe.universe, fe.text_index)
-            if parsed["stream"]:
-                self._stream_completion(q, path, t0, parsed["gen"])
-            else:
-                self._unary_completion(q, path, t0, parsed["gen"])
-        except ApiError as e:
-            self._send_json(e.status, e.body(), path)
-        except (BrokenPipeError, ConnectionResetError):
-            pass
-        except Exception as e:   # noqa: BLE001 — wire boundary
-            self._send_json(500, ApiError(500, f"internal error: {e}",
-                                          "server_error").body(), path)
+        with tracing.span("http.request") as sp:
+            try:
+                length = int(self.headers.get("Content-Length") or 0)
+                parsed = parse_chat_body(self.rfile.read(length))
+                q = resolve_query_idx(parsed, fe.universe, fe.text_index)
+                if parsed["stream"]:
+                    self._stream_completion(q, path, t0, parsed["gen"], sp)
+                else:
+                    self._unary_completion(q, path, t0, parsed["gen"], sp)
+            except ApiError as e:
+                self._send_json(e.status, e.body(), path)
+            except (BrokenPipeError, ConnectionResetError):
+                pass
+            except Exception as e:   # noqa: BLE001 — wire boundary
+                self._send_json(500, ApiError(500, f"internal error: {e}",
+                                              "server_error").body(), path)
 
     def _model_name(self, req) -> Optional[str]:
         fe = self.server.frontend
         return fe.server.pool[req.model].name if req.model is not None else None
 
     def _unary_completion(self, q: int, path: str, t0: float,
-                          gen=None) -> None:
+                          gen=None, sp=tracing.OFF) -> None:
         fe = self.server.frontend
         req = fe.server.submit_request(q, stream=False, gen=gen)
+        sp.tag(rid=req.rid)
         if not req.done_event.wait(fe.request_timeout_s):
             raise ApiError(504, "request timed out in the serving queue",
                            "timeout_error")
@@ -156,9 +159,10 @@ class _Handler(BaseHTTPRequestHandler):
             fe._http_latency.labels(mode="unary").observe(time.perf_counter() - t0)
 
     def _stream_completion(self, q: int, path: str, t0: float,
-                           gen=None) -> None:
+                           gen=None, sp=tracing.OFF) -> None:
         fe = self.server.frontend
         req = fe.server.submit_request(q, stream=True, gen=gen)
+        sp.tag(rid=req.rid)
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream")
         self.send_header("Cache-Control", "no-cache")

@@ -136,5 +136,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)[:, :Sq]

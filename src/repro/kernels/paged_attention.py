@@ -117,5 +117,6 @@ def paged_attention_pallas(q, k_pool, v_pool, table, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, 1, H, D)

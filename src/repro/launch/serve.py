@@ -214,7 +214,7 @@ def engine_main(argv):
           f"({out_toks} tokens) in {dt:.1f}s via {engine.max_slots} slots")
     occ = engine.kv_occupancy()
     if occ.get("paged"):
-        print(f"  kv pages: {occ['pages_used']}/{occ['n_pages']} live "
+        print(f"  kv pages: {occ['pages_used']}/{occ['pages_reserved']} live "
               f"(peak {occ['peak_pages']}), {occ['prefix_shares']} prefix "
               f"shares, {occ['cow_forks']} CoW forks")
     if hasattr(engine, "accept_rate"):

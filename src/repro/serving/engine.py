@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.data.tokenizer import ByteTokenizer
 from repro.models.transformer import Model
+from repro.serving import tracing
 from repro.serving.generation import GenerationConfig
 from repro.serving.kvpool import PagedCacheManager
 
@@ -153,6 +154,8 @@ class ServingEngine:
         self.n_decode_calls = 0             # host→device decode dispatches
         self.n_decode_steps = 0             # device decode steps they executed
         self.n_prefill_calls = 0            # admission prefill dispatches
+        self.kv_pages_in_use_sum = 0        # pages mapped at each paged decode
+        #   dispatch, summed over dispatches (see kv_occupancy)
 
         @jax.jit
         def _decode(params, tokens, cache):
@@ -493,12 +496,14 @@ class ServingEngine:
                 temp[j] = g.temperature
                 top_k[j] = g.top_k
                 top_p[j] = g.top_p
-            first = np.asarray(self._pick_tokens(
-                logits[:, 0], jnp.asarray(keys),
-                jnp.zeros(rows_n, jnp.int32), jnp.asarray(temp),
-                jnp.asarray(top_k), jnp.asarray(top_p)))
+            with tracing.span("engine.fetch"):
+                first = np.asarray(self._pick_tokens(
+                    logits[:, 0], jnp.asarray(keys),
+                    jnp.zeros(rows_n, jnp.int32), jnp.asarray(temp),
+                    jnp.asarray(top_k), jnp.asarray(top_p)))
         else:
-            first = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            with tracing.span("engine.fetch"):
+                first = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
         now = time.time()
         for req, slot, f in zip(reqs, slots, first):
             self.slot_req[slot] = req
@@ -519,7 +524,8 @@ class ServingEngine:
             if not free:
                 return
             n = min(len(free), len(queue))
-            self._admit_batch([queue.pop(0) for _ in range(n)], free[:n])
+            with tracing.span("engine.admit"):
+                self._admit_batch([queue.pop(0) for _ in range(n)], free[:n])
 
     def _retire(self, slot: int):
         req = self.slot_req[slot]
@@ -637,24 +643,33 @@ class ServingEngine:
         return jnp.asarray(self.kv.table[:, :n_cols])
 
     def kv_occupancy(self) -> dict:
-        """KV memory telemetry for the serving plane (WindowReport / bench).
+        """KV memory and dispatch telemetry for the serving plane
+        (WindowReport, the metrics registry, the bench).
 
         Contiguous engines report the committed buffer size (every slot owns
-        a full ``max_len`` row whether it uses it or not); paged engines
-        report live and peak *mapped* bytes — distinct physical pages times
-        per-page bytes summed across layers — which is what prefix sharing
-        and page-granular growth actually save.
+        a full ``max_len`` row whether it uses it or not).  Paged engines
+        report the pool they reserve (``pages_reserved``, ``reserved_bytes``)
+        beside live and peak *mapped* bytes (distinct physical pages times
+        per-page bytes summed across layers), and ``pages_in_use_sum``, the
+        pages mapped at each decode dispatch summed over dispatches:
+        ``pages_in_use_sum / (decode_calls * pages_reserved)`` is the share
+        of the reservation that decode used.  Both report their dispatch
+        counts (``decode_calls``, ``prefill_calls``).
         """
         kv_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(self.cache)
                        if leaf.ndim >= 3)
+        calls = {"decode_calls": self.n_decode_calls,
+                 "prefill_calls": self.n_prefill_calls}
         if not self.paged:
             return {"paged": False, "kv_bytes": kv_bytes,
-                    "peak_kv_bytes": kv_bytes}
+                    "peak_kv_bytes": kv_bytes, **calls}
         occ = self.kv.occupancy()
-        page_bytes = kv_bytes // max(self.kv.alloc.n_pages, 1)
+        page_bytes = kv_bytes // max(occ["pages_reserved"], 1)
         occ.update(paged=True, page_bytes=page_bytes,
                    kv_bytes=occ["pages_used"] * page_bytes,
-                   peak_kv_bytes=occ["peak_pages"] * page_bytes)
+                   peak_kv_bytes=occ["peak_pages"] * page_bytes,
+                   reserved_bytes=kv_bytes,
+                   pages_in_use_sum=self.kv_pages_in_use_sum, **calls)
         return occ
 
     # ------------------------------------------------------------------
@@ -666,45 +681,53 @@ class ServingEngine:
         every active slot before the host looks at the results again.
         """
         queue = list(requests)
-        while queue or self._active_slots():
-            self._admit_free(queue)
-            active = self._active_slots()
-            if not active:
-                continue
-            last, act, n_out, limit = self._slot_state()
-            sample, keys, temp, top_k, top_p = self._sampling_state()
-            kw = {}
-            if sample:
-                kw = dict(keys=jnp.asarray(keys), temp=jnp.asarray(temp),
-                          top_k=jnp.asarray(top_k), top_p=jnp.asarray(top_p),
-                          sample=True)
-            live = max(len(self.slot_req[i].tokens) + len(self.slot_req[i].out_tokens)
-                       for i in active)
-            horizon = min(self.max_len, self._bucket_len(live + self.decode_block))
-            if self.paged:
-                table = self._prepare_paged(active, horizon)
-                self.cache, act_f, toks, valid = self._decode_k_paged(
-                    self.params, self.cache, table, jnp.asarray(last),
-                    jnp.asarray(act), jnp.asarray(n_out), jnp.asarray(limit),
-                    **kw)
-            else:
-                self.cache, act_f, toks, valid = self._decode_k(
-                    horizon, self.params, self.cache, jnp.asarray(last),
-                    jnp.asarray(act), jnp.asarray(n_out), jnp.asarray(limit),
-                    **kw)
-            self.n_decode_calls += 1
-            self.n_decode_steps += self.decode_block
-            toks = np.asarray(toks)
-            valid = np.asarray(valid)
-            act_f = np.asarray(act_f)
-            for i in active:
-                req = self.slot_req[i]
-                block = [int(t) for t in toks[valid[:, i], i]]
-                req.out_tokens.extend(block)
-                if not act_f[i]:
-                    self._retire(i)
-                if req.on_tokens is not None:
-                    req.on_tokens(block, req.done)
+        with tracing.span("engine.serve", inv=requests[0].rid if requests else -1):
+            while queue or self._active_slots():
+                self._admit_free(queue)
+                active = self._active_slots()
+                if not active:
+                    continue
+                with tracing.span("engine.prepare"):
+                    last, act, n_out, limit = self._slot_state()
+                    sample, keys, temp, top_k, top_p = self._sampling_state()
+                    kw = {}
+                    if sample:
+                        kw = dict(keys=jnp.asarray(keys), temp=jnp.asarray(temp),
+                                  top_k=jnp.asarray(top_k), top_p=jnp.asarray(top_p),
+                                  sample=True)
+                    live = max(len(self.slot_req[i].tokens)
+                               + len(self.slot_req[i].out_tokens) for i in active)
+                    horizon = min(self.max_len,
+                                  self._bucket_len(live + self.decode_block))
+                    if self.paged:
+                        table = self._prepare_paged(active, horizon)
+                        self.kv_pages_in_use_sum += self.kv.alloc.pages_in_use
+                with tracing.span("engine.decode"):
+                    if self.paged:
+                        self.cache, act_f, toks, valid = self._decode_k_paged(
+                            self.params, self.cache, table, jnp.asarray(last),
+                            jnp.asarray(act), jnp.asarray(n_out), jnp.asarray(limit),
+                            **kw)
+                    else:
+                        self.cache, act_f, toks, valid = self._decode_k(
+                            horizon, self.params, self.cache, jnp.asarray(last),
+                            jnp.asarray(act), jnp.asarray(n_out), jnp.asarray(limit),
+                            **kw)
+                self.n_decode_calls += 1
+                self.n_decode_steps += self.decode_block
+                with tracing.span("engine.fetch"):
+                    toks = np.asarray(toks)
+                    valid = np.asarray(valid)
+                    act_f = np.asarray(act_f)
+                with tracing.span("engine.emit"):
+                    for i in active:
+                        req = self.slot_req[i]
+                        block = [int(t) for t in toks[valid[:, i], i]]
+                        req.out_tokens.extend(block)
+                        if not act_f[i]:
+                            self._retire(i)
+                        if req.on_tokens is not None:
+                            req.on_tokens(block, req.done)
         return requests
 
     def serve_stepwise(self, requests: list[Request]) -> list[Request]:

@@ -236,7 +236,7 @@ class PagedCacheManager:
     def occupancy(self) -> dict:
         a = self.alloc
         return {
-            "n_pages": a.n_pages, "page_size": a.page_size,
+            "pages_reserved": a.n_pages, "page_size": a.page_size,
             "pages_used": a.pages_in_use, "pages_shared": a.pages_shared,
             "peak_pages": a.peak_pages, "cow_forks": a.n_forks,
             "prefix_shares": a.n_shares,

@@ -99,6 +99,7 @@ from typing import Iterable, Iterator, Optional, Sequence
 import numpy as np
 
 from repro.core.scheduler import attach_free_assignments, restrict_space, take_rows
+from repro.serving import tracing
 from repro.serving.autoscale import Autoscaler, AutoscalePolicy
 from repro.serving.fault import BreakerPolicy, CircuitBreaker, CircuitState
 from repro.serving.generation import GenerationConfig
@@ -195,6 +196,10 @@ class OnlineRequest:
     rid: int
     query_idx: int
     arrived_at: float
+    admitted_at: Optional[float] = None   # start of the round that dispatched it
+    served_at: Optional[float] = None     # its member began serving its group
+    #   (both on the serving timeline, like arrived_at: window wait is
+    #   admitted_at - arrived_at, member wait served_at - admitted_at)
     completed_at: Optional[float] = None
     utility: Optional[float] = None
     model: Optional[int] = None
@@ -303,6 +308,11 @@ class OnlineConfig:
     #   on its own default (the legacy greedy path, bit-identical)
 
 
+ENGINE_COUNTS = ("decode_calls", "prefill_calls", "pages_reserved", "pages_in_use_sum")
+#   the kv_occupancy() counters a WindowReport carries (see
+#   repro.serving.engine.ServingEngine.kv_occupancy)
+
+
 @dataclass
 class WindowReport:
     """One scheduling round's accounting (the server keeps the full list)."""
@@ -323,7 +333,6 @@ class WindowReport:
     avail: float = 0.0                # bucket balance when the round started
     est_cost: float = 0.0             # amortized cost the scheduler committed
     spent: float = 0.0                # realized billed cost (Eq. 4 semantics)
-    open_models: tuple = ()           # breaker-open member names
     group_models: tuple = ()          # model index of each dispatched group
     late_s: float = 0.0               # realtime: how late past the boundary
     replica_counts: tuple = ()        # active replicas per member after the round
@@ -342,6 +351,10 @@ class WindowReport:
     #   autoscale actions fired on THIS round's control tick — the per-member
     #   attribution the metrics registry turns into
     #   robatch_scale_events_total{member, direction}
+    engine_counts: tuple = ()         # ((member_idx, {counter: total}), ...)
+    #   cumulative ENGINE_COUNTS of each member with a real engine behind it,
+    #   read at the round's end: the metrics registry exports them, and the
+    #   difference between two rounds scopes them to the rounds between
 
     @property
     def kv_occupancy(self) -> int:
@@ -477,7 +490,7 @@ class OnlineRobatchServer:
         # the serving thread — keep them fast and non-blocking
         self.on_window = None         # fn(WindowReport) after every round
         self.on_complete = None       # fn(OnlineRequest) at every completion
-        self._bridge_t0: Optional[float] = None   # run_bridge timeline origin
+        self._t0: Optional[float] = None  # clock origin of a wall-paced loop
 
     # ------------------------------------------------------------- admission
     def submit(self, query_idx: int, at: Optional[float] = None) -> OnlineRequest:
@@ -499,8 +512,8 @@ class OnlineRobatchServer:
         current tick.  ``gen`` overrides the server's default
         :class:`GenerationConfig` for this request (sampled requests bypass
         the response caches — a cached sample is not a fresh draw)."""
-        if at is None and self._bridge_t0 is not None:
-            at = self.clock.now() - self._bridge_t0
+        if at is None and self._t0 is not None:
+            at = self._timeline()
         with self._submit_lock:
             # sink and event attach BEFORE the request becomes visible to the
             # serving loop — a step() racing ahead must find them in place
@@ -585,17 +598,36 @@ class OnlineRobatchServer:
                     return req.gen
         return self.cfg.generation
 
-    def _invoke(self, k: int, members: np.ndarray, streams=None, gen=None):
+    def _timeline(self) -> Optional[float]:
+        """The serving timeline's now while a wall-paced loop runs (None on
+        the virtual clock, whose now is the round's)."""
+        t0 = self._t0
+        return None if t0 is None else self.clock.now() - t0
+
+    def _invoke(self, k: int, members: np.ndarray, streams=None, gen=None,
+                parent: int = 0, rids: tuple = ()):
+        """One group on member ``k``, on a dispatch thread: the member's
+        result and the timeline time it began the group (None when virtual).
+        ``parent`` and ``rids`` name the submitting round's span and the
+        group's requests on the ``pool.invoke`` span."""
         kw = {"streams": streams} if streams else {}
         if gen is not None and getattr(self.pool[k], "supports_generation",
                                        False):
             kw["gen"] = gen
-        if getattr(self.pool[k], "thread_safe", False):
+        with tracing.span("pool.invoke", parent=parent, member=k, rids=rids):
             # ReplicaSets serialize per replica internally — concurrent groups
-            # on one member are exactly what the replicas are for
-            return self.pool[k].invoke_batch(self.wl, members, **kw)
-        with self._locks[k]:          # engines are not thread-safe; members are
-            return self.pool[k].invoke_batch(self.wl, members, **kw)
+            # on one member are exactly what the replicas are for; engines
+            # are not thread-safe, so other members take one group at a time
+            lock = None if getattr(self.pool[k], "thread_safe", False) else self._locks[k]
+            if lock is not None:
+                with tracing.span("pool.lock"):
+                    lock.acquire()
+            try:
+                started = self._timeline()
+                return self.pool[k].invoke_batch(self.wl, members, **kw), started
+            finally:
+                if lock is not None:
+                    lock.release()
 
     def _finish_window(self, rep: WindowReport) -> WindowReport:
         """Seal one round: record per-member replica counts, give the
@@ -603,15 +635,18 @@ class OnlineRobatchServer:
         NEXT round plans against), and append the report."""
         rep.replica_counts = tuple(int(getattr(m, "n_replicas", 1))
                                    for m in self.pool)
-        kv = []
+        kv, counts = [], []
         for k, m in enumerate(self.pool):
             fn = getattr(m, "kv_occupancy", None)
             occ = fn() if fn is not None else None
+            if occ:
+                counts.append((k, {c: int(occ.get(c, 0)) for c in ENGINE_COUNTS}))
             if occ and occ.get("paged"):
                 kv.append((k, int(occ.get("pages_used", 0)),
                            int(occ.get("pages_shared", 0)),
                            int(occ.get("cow_forks", 0))))
         rep.kv_pages = tuple(kv)
+        rep.engine_counts = tuple(counts)
         if self.autoscaler is not None:
             fired = self.autoscaler.observe(rep, len(self.pending), rep.t)
             rep.scale_events = tuple((e.member, e.from_n, e.to_n) for e in fired)
@@ -624,6 +659,10 @@ class OnlineRobatchServer:
 
     def step(self, now: Optional[float] = None) -> WindowReport:
         """Run one scheduling round over the queries pending at ``now``."""
+        with tracing.span("window.step") as sp:
+            return self._round(now, sp.span_id)
+
+    def _round(self, now: Optional[float], span_id: int) -> WindowReport:
         self.now = self.now + self.cfg.window_s if now is None else now
         now = self.now
         rep = WindowReport(t=now, n_pending=len(self.pending))
@@ -672,8 +711,6 @@ class OnlineRobatchServer:
         self.n_coalesced += rep.n_coalesced
 
         allowed = self.allowed_models()
-        rep.open_models = tuple(self.pool[k].name for k, br in enumerate(self.breakers)
-                                if br.state == CircuitState.OPEN)
         if not by_idx or not allowed:
             # requeue front-of-queue in FCFS order (iterate groups backwards)
             for reqs in reversed(list(by_idx.values())):
@@ -716,8 +753,9 @@ class OnlineRobatchServer:
         #    capacity caps ride along when the policy understands them
         caps = self.caps()
         cap_kw = {"caps": caps or None} if self._pw_caps else {}
-        wplan = self.policy.plan_window(take_rows(space, np.arange(n_adm)), idx,
-                                        avail, **cap_kw)
+        with tracing.span("window.plan"):
+            wplan = self.policy.plan_window(take_rows(space, np.arange(n_adm)),
+                                            idx, avail, **cap_kw)
         if wplan.schedule is not None and sem_utils:
             # core-scheduler accounting: semantic hits enter the window's
             # ScheduleResult as (cost=0, utility=u·(1−ε)) assignments, so
@@ -776,59 +814,72 @@ class OnlineRobatchServer:
         #    text get the live per-position subscriber sinks so SSE deltas
         #    flow at decode-block cadence (simulators stream at completion)
         futures = {}
-        for state, members in dispatch:
-            k = int(state.model)
-            streams = None
-            if getattr(self.pool[k], "supports_streams", False):
-                streams = {pos: sinks for pos, q in enumerate(members)
-                           if (sinks := [r.stream for r in by_idx[int(q)]
-                                         if r.stream is not None])}
-            gen = self._group_gen(members, by_idx)
-            fut = self._pool_exec.submit(self._invoke, k, members,
-                                         streams or None, gen)
-            futures[fut] = (state, members, gen)
-        rep.n_groups = len(dispatch)
-        rep.group_models = tuple(int(s.model) for s, _ in dispatch)
+        with tracing.span("window.dispatch", groups=len(dispatch)):
+            for state, members in dispatch:
+                k = int(state.model)
+                grp = [req for q in members for req in by_idx[int(q)]]
+                for req in grp:
+                    req.admitted_at = now
+                streams = None
+                if getattr(self.pool[k], "supports_streams", False):
+                    streams = {pos: sinks for pos, q in enumerate(members)
+                               if (sinks := [r.stream for r in by_idx[int(q)]
+                                             if r.stream is not None])}
+                gen = self._group_gen(members, by_idx)
+                fut = self._pool_exec.submit(self._invoke, k, members,
+                                             streams or None, gen, span_id,
+                                             tuple(r.rid for r in grp))
+                futures[fut] = (state, members, gen, grp)
+            rep.n_groups = len(dispatch)
+            rep.group_models = tuple(int(s.model) for s, _ in dispatch)
 
-        requeue: list[OnlineRequest] = []
-        for fut, (state, members, gen) in futures.items():
-            k = int(state.model)
-            try:
-                out = fut.result()
-            except Exception:         # noqa: BLE001 — member fault
-                probe_failed = k in half_open     # expected-risk probe traffic
-                self.breakers[k].record_failure()
-                for q in members:
+            requeue: list[OnlineRequest] = []
+            for fut, (state, members, gen, grp) in futures.items():
+                k = int(state.model)
+                try:
+                    out, started = fut.result()
+                except Exception:         # noqa: BLE001 — member fault
+                    probe_failed = k in half_open     # expected-risk probe traffic
+                    self.breakers[k].record_failure()
+                    for q in members:
+                        for req in by_idx[int(q)]:
+                            rep.n_failed += 1
+                            if not probe_failed:
+                                req.n_reroutes += 1
+                            if req.n_reroutes > self.cfg.max_reroutes:
+                                self._complete(req, at=now, utility=0.0, model=None,
+                                               batch=None, cost=0.0, dropped=True)
+                            else:
+                                requeue.append(req)
+                    continue
+                self.breakers[k].record_success()
+                cost = (out.in_tokens * self.pool[k].c_in
+                        + out.out_tokens * self.pool[k].c_out) / 1e6
+                self.bucket.spend(cost)
+                rep.spent += cost
+                # on a wall clock the group completes no earlier than now: a
+                # group served after others on one member waited for them,
+                # which its own service time (latency_s) leaves out
+                done_at = now + float(out.latency_s)
+                handled = self._timeline()
+                if handled is not None:
+                    done_at = max(done_at, handled)
+                for req in grp:
+                    req.served_at = now if started is None else started
+                share = cost / max(1, len(members))
+                answers = getattr(out, "answers", None)
+                cacheable = gen is None or gen.greedy
+                for pos, (q, u) in enumerate(zip(members, out.utilities)):
+                    text = answers[pos] if answers is not None else None
+                    if cacheable:      # one sample must not become every answer
+                        self.cache.put(int(q), (float(u), k, text))
+                        if self.semcache is not None:
+                            self.semcache.insert(int(q), float(u), k, text,
+                                                 now=done_at)
                     for req in by_idx[int(q)]:
-                        rep.n_failed += 1
-                        if not probe_failed:
-                            req.n_reroutes += 1
-                        if req.n_reroutes > self.cfg.max_reroutes:
-                            self._complete(req, at=now, utility=0.0, model=None,
-                                           batch=None, cost=0.0, dropped=True)
-                        else:
-                            requeue.append(req)
-                continue
-            self.breakers[k].record_success()
-            cost = (out.in_tokens * self.pool[k].c_in
-                    + out.out_tokens * self.pool[k].c_out) / 1e6
-            self.bucket.spend(cost)
-            rep.spent += cost
-            done_at = now + float(out.latency_s)
-            share = cost / max(1, len(members))
-            answers = getattr(out, "answers", None)
-            cacheable = gen is None or gen.greedy
-            for pos, (q, u) in enumerate(zip(members, out.utilities)):
-                text = answers[pos] if answers is not None else None
-                if cacheable:      # one sample must not become every answer
-                    self.cache.put(int(q), (float(u), k, text))
-                    if self.semcache is not None:
-                        self.semcache.insert(int(q), float(u), k, text,
-                                             now=done_at)
-                for req in by_idx[int(q)]:
-                    self._complete(req, at=done_at, utility=float(u), model=k,
-                                   batch=int(state.batch), cost=share,
-                                   content=text)
+                        self._complete(req, at=done_at, utility=float(u), model=k,
+                                       batch=int(state.batch), cost=share,
+                                       content=text)
         retry = sorted(requeue + held, key=lambda r: r.rid)
         if retry:                     # FCFS: oldest retried request re-enters first
             self.pending.extendleft(reversed(retry))
@@ -869,22 +920,25 @@ class OnlineRobatchServer:
         round.  A slow round never skips a window — the next rounds fire
         back-to-back and the overshoot lands in ``WindowReport.late_s``."""
         clock = self.clock
-        t0 = clock.now()
+        t0 = self._t0 = clock.now()
         pos = 0
-        for tick in range(1, max_ticks + 1):
-            if pos >= len(arrivals) and not self.pending:
-                break
-            target = tick * self.cfg.window_s
-            lag = target - (clock.now() - t0)
-            if lag > 0:
-                clock.sleep(lag)
-            now = clock.now() - t0
-            while pos < len(arrivals) and arrivals[pos][0] <= now:
-                at, q = arrivals[pos]
-                self.submit(q, at=at)
-                pos += 1
-            rep = self.step(now)
-            rep.late_s = max(0.0, now - target)
+        try:
+            for tick in range(1, max_ticks + 1):
+                if pos >= len(arrivals) and not self.pending:
+                    break
+                target = tick * self.cfg.window_s
+                lag = target - (clock.now() - t0)
+                if lag > 0:
+                    clock.sleep(lag)
+                now = clock.now() - t0
+                while pos < len(arrivals) and arrivals[pos][0] <= now:
+                    at, q = arrivals[pos]
+                    self.submit(q, at=at)
+                    pos += 1
+                rep = self.step(now)
+                rep.late_s = max(0.0, now - target)
+        finally:
+            self._t0 = None
         return self.stats()
 
     def run_bridge(self, stop_event: threading.Event, *,
@@ -900,8 +954,7 @@ class OnlineRobatchServer:
         completed as dropped — a waiter on ``done_event`` is never stranded.
         """
         clock = self.clock
-        t0 = clock.now()
-        self._bridge_t0 = t0
+        t0 = self._t0 = clock.now()
         try:
             for tick in range(1, max_ticks + 1):
                 if stop_event.is_set():
@@ -926,7 +979,7 @@ class OnlineRobatchServer:
                 self._complete(req, at=clock.now() - t0, utility=0.0,
                                model=None, batch=None, cost=0.0, dropped=True)
         finally:
-            self._bridge_t0 = None
+            self._t0 = None
 
     def run_live(self, arrivals: Sequence[tuple[float, int]], *,
                  duration_s: Optional[float] = None,
@@ -956,7 +1009,7 @@ class OnlineRobatchServer:
         if duration_s is None:
             duration_s = arrivals[-1][0] if arrivals else 0.0
         clock = self.clock
-        t0 = clock.now()
+        t0 = self._t0 = clock.now()
         source = LiveArrivalSource(self, arrivals, t0=t0)
         source.start()
         try:
@@ -971,6 +1024,7 @@ class OnlineRobatchServer:
                 if now >= duration_s and not source.is_alive() and not self.pending:
                     break
         finally:
+            self._t0 = None
             source.stop()
             source.join(timeout=join_timeout_s)
             self.pacer_leaked = bool(source.is_alive())

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.data.simulator import BatchResult, evaluate_chunked
 from repro.data.workload import Workload
+from repro.serving import tracing
 from repro.serving.batcher import BatchPromptFormatter
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.fault import ReplicaPolicy, ReplicaTracker
@@ -119,20 +120,21 @@ class ServedPoolMember:
                     sink.push(delta)
 
         def on_tokens(new_ids: list[int], done: bool) -> None:
-            acc.extend(new_ids)
-            ids, ended = acc, done
-            if eos in ids:
-                ids, ended = ids[: ids.index(eos)], True
-            raw = bytes(i for i in ids if i < 256)
-            parts = raw.split(sep)
-            for pos in streams:
-                if pos >= b or pos >= len(parts) or closed[pos]:
-                    continue
-                if pos < len(parts) - 1 or ended:
-                    closed[pos] = True
-                    push(pos, parts[pos].decode("utf-8", errors="replace").strip())
-                else:
-                    push(pos, clean_prefix(parts[pos]).strip())
+            with tracing.span("pool.demux"):
+                acc.extend(new_ids)
+                ids, ended = acc, done
+                if eos in ids:
+                    ids, ended = ids[: ids.index(eos)], True
+                raw = bytes(i for i in ids if i < 256)
+                parts = raw.split(sep)
+                for pos in streams:
+                    if pos >= b or pos >= len(parts) or closed[pos]:
+                        continue
+                    if pos < len(parts) - 1 or ended:
+                        closed[pos] = True
+                        push(pos, parts[pos].decode("utf-8", errors="replace").strip())
+                    else:
+                        push(pos, clean_prefix(parts[pos]).strip())
 
         return on_tokens
 
@@ -156,17 +158,22 @@ class ServedPoolMember:
                       max_new=self.max_answer_tokens * b + b, gen=effective)
         if streams:
             req.on_tokens = self._stream_demux(b, streams)
-        with self._lock:              # one engine, one in-flight batch
+        with tracing.span("pool.lock", inv=req.rid):
+            self._lock.acquire()      # one engine, one in-flight batch
+        try:
             self.engine.serve([req])
+        finally:
+            self._lock.release()
         latency = time.perf_counter() - t0
-        tok = self.formatter.tokenizer
-        out_ids = req.out_tokens
-        if self.engine.eos_id in out_ids:
-            out_ids = out_ids[: out_ids.index(self.engine.eos_id)]
-        text = tok.decode(out_ids)
-        answers = self.formatter.parse(text, b)
-        util = np.array([self.task.judge(a, self.task.answers[int(i)])
-                         for a, i in zip(answers, batch_idx)])
+        with tracing.span("pool.parse", inv=req.rid):
+            tok = self.formatter.tokenizer
+            out_ids = req.out_tokens
+            if self.engine.eos_id in out_ids:
+                out_ids = out_ids[: out_ids.index(self.engine.eos_id)]
+            text = tok.decode(out_ids)
+            answers = self.formatter.parse(text, b)
+            util = np.array([self.task.judge(a, self.task.answers[int(i)])
+                             for a, i in zip(answers, batch_idx)])
         return BatchResult(utilities=util, in_tokens=len(prompt),
                            out_tokens=len(req.out_tokens), latency_s=latency,
                            answers=answers)

@@ -266,6 +266,34 @@ def test_metrics_prometheus_text_parses(base, pool):
     assert any('le="+Inf"' in ln for ln in bucket_lines)
 
 
+def test_cache_hits_is_a_counter_and_queue_waits_are_split(base):
+    import time
+
+    for _ in range(2):           # the second is an exact response-cache hit
+        with _post(base, {"messages": [{"role": "user", "content": "#17"}],
+                          "query_idx": 17}) as r:
+            r.read()
+    deadline = time.monotonic() + 5.0
+    while True:                  # the hit is exported at its round's end
+        with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
+            text = r.read().decode()
+        hits = re.search(r"^robatch_cache_hits_total (\S+)$", text, re.M)
+        if (hits and float(hits.group(1)) >= 1) or time.monotonic() > deadline:
+            break
+        time.sleep(0.05)
+    types = dict(line.split()[2:4] for line in text.splitlines()
+                 if line.startswith("# TYPE "))
+    assert types["robatch_cache_hits_total"] == "counter"
+    assert float(hits.group(1)) >= 1
+    assert types["robatch_queue_wait_seconds"] == "histogram"
+    for stage in ("window", "member"):
+        m = re.search(rf'^robatch_queue_wait_seconds_count{{stage="{stage}"}} (\d+)$',
+                      text, re.M)
+        assert m and int(m.group(1)) >= 1, stage
+    assert types["robatch_engine_calls_total"] == "counter"
+    assert types["robatch_compiles_total"] == "counter"
+
+
 def test_metrics_registry_binds_to_gateway_serve(fitted_rb, pool, agnews):
     """Gateway.serve(metrics=...) populates the same registry the HTTP
     surface renders — no front-end required."""

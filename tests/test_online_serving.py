@@ -468,6 +468,47 @@ def test_virtual_and_realtime_replay_one_seeded_stream_identically(
     assert v_stats.qps == pytest.approx(r_stats.qps)
 
 
+class _SlowMember:
+    """A simulated member whose every group takes ``service_s`` of wall
+    time, and says so in its own ``latency_s``."""
+
+    def __init__(self, inner, service_s):
+        self.inner, self.service_s = inner, service_s
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+    def invoke_batch(self, wl, batch_idx, **kw):
+        time.sleep(self.service_s)
+        out = self.inner.invoke_batch(wl, batch_idx)
+        out.latency_s = self.service_s
+        return out
+
+
+def test_realtime_completion_counts_the_wait_behind_earlier_groups(
+        fitted_rb, agnews, pool):
+    """One dispatch thread serves a round's groups one after another: on the
+    wall clock a later group completes after the earlier groups' service,
+    which its own latency_s leaves out.  The stamps split the wait."""
+    test = agnews.subset_indices("test")
+    service = 0.05
+    cfg = OnlineConfig(budget_per_s=_rate(fitted_rb, test, 100.0), window_s=0.25,
+                       realtime=True, max_workers=1)
+    srv = OnlineRobatchServer(fitted_rb, [_SlowMember(m, service) for m in pool],
+                              agnews, cfg)
+    srv.run([(0.01, int(q)) for q in test[:40]])
+    srv.close()
+    rep = next(w for w in srv.windows if w.n_groups >= 2)
+    done = [r for r in srv.completed if r.admitted_at == rep.t]
+    assert len({r.served_at for r in done}) == rep.n_groups
+    for r in done:
+        assert r.admitted_at - r.arrived_at == pytest.approx(rep.t - 0.01)
+        assert r.completed_at >= r.served_at + service
+    # the last group began after every other group's service
+    assert max(r.served_at for r in done) >= rep.t + service * (rep.n_groups - 1)
+    assert max(r.completed_at for r in done) >= rep.t + service * rep.n_groups
+
+
 def test_run_live_submits_the_stream_from_a_pacer_thread(
         fitted_rb, agnews, pool):
     test = agnews.subset_indices("test")
