@@ -140,6 +140,7 @@ def kernels_match_oracles(cfg, shapes: dict, seed: int, check: Checks) -> None:
 
     from repro.config import ShardingConfig
     from repro.kernels.flash_attention import flash_attention_pallas
+    from repro.kernels.ops import pack_pages, pool_geometry
     from repro.kernels.paged_attention import paged_attention_pallas
     from repro.kernels.ref import decode_attention_ref, mha_ref
 
@@ -151,22 +152,30 @@ def kernels_match_oracles(cfg, shapes: dict, seed: int, check: Checks) -> None:
 
     _, (_, cache, table, *_), _ = shapes["_decode_k_paged"]
     B, n_cols = table.shape
-    P, ps = next(x.shape[-4:-2] for x in jax.tree.leaves(cache) if x.ndim >= 4)
+    pool = next(x for x in jax.tree.leaves(cache) if x.ndim == 5)
+    L, P = pool.shape[:2]
+    ps = pool_geometry(pool, D)[2]
+    layer = int(rng.integers(L))
     q = normal(B, 1, H, D)
-    k_pool, v_pool = normal(P, ps, Hk, D), normal(P, ps, Hk, D)
+    k_pages, v_pages = normal(L, P, Hk, ps, D), normal(L, P, Hk, ps, D)
     lengths = rng.integers(1, n_cols * ps + 1, B).astype(np.int32)
     tab = rng.permutation(P)[: B * n_cols].reshape(B, n_cols).astype(np.int32)
     tab[np.arange(n_cols)[None] * ps >= lengths[:, None]] = P     # unmapped
-    got = jax.jit(paged_attention_pallas)(q, k_pool, v_pool, jnp.asarray(tab),
-                                          jnp.asarray(lengths))
+    got = jax.jit(paged_attention_pallas)(q, pack_pages(k_pages), pack_pages(v_pages),
+                                          jnp.asarray(tab), jnp.asarray(lengths), layer)
     safe = jnp.asarray(np.minimum(tab, P - 1))
+
+    def rows(pages):                        # (B, n_cols * ps, Hk, D) in f32
+        return (pages[layer, safe].transpose(0, 1, 3, 2, 4)
+                .reshape(B, n_cols * ps, Hk, D).astype(jnp.float32))
+
     with jax.default_matmul_precision("highest"):
-        k = k_pool[safe].reshape(B, n_cols * ps, Hk, D).astype(jnp.float32)
-        v = v_pool[safe].reshape(B, n_cols * ps, Hk, D).astype(jnp.float32)
-        want = decode_attention_ref(q.astype(jnp.float32), k, v, jnp.asarray(lengths))
+        want = decode_attention_ref(q.astype(jnp.float32), rows(k_pages),
+                                    rows(v_pages), jnp.asarray(lengths))
     ok, err = within_tolerance(got, want)
     check("paged_attention_pallas vs decode_attention_ref", ok,
-          f"B={B} pages={P}x{ps} table_cols={n_cols} max_abs_err={err:.3e}")
+          f"B={B} pages={L}x{P}x{ps} layer={layer} table_cols={n_cols} "
+          f"max_abs_err={err:.3e}")
 
     _, (_, tokens, *_), _ = shapes["_prefill"]
     Bp, L = tokens.shape

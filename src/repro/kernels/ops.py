@@ -12,6 +12,7 @@ same asymptotic memory behaviour the TPU kernels deliver.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -194,38 +195,129 @@ def _decode_jnp(q, k, v, lengths):
 
 
 # ---------------------------------------------------------------------------
+# paged KV pool layout (one module owns it: the model writes through
+# write_tokens, the engine inserts through pack_pages, every read goes
+# through gather_pages or the kernel)
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def pool_packing(head_dim: int, page_size: int) -> tuple[int, int]:
+    """``(R, Dp)``: a pool row holds ``R`` consecutive positions of one page
+    and head, ``Dp`` lanes each.  A head dim that divides 128 packs
+    ``128 / head_dim`` positions into one 128-lane row (fewer where the page
+    is shorter), with no padding; any other is zero-padded to whole 128-lane
+    rows, one position each."""
+    if LANES % head_dim == 0:
+        return math.gcd(LANES // head_dim, page_size), head_dim
+    return 1, -(-head_dim // LANES) * LANES
+
+
+def pool_shape(n_layers: int, n_pages: int, n_kv_heads: int, page_size: int,
+               head_dim: int) -> tuple[int, ...]:
+    """The paged K (or V) pool of a layer stack: ``(L, P, Hk, page_size / R,
+    R * Dp)`` (:func:`pool_packing`).  Its row-major order is the paged
+    kernel's, one page of one head of one layer is one contiguous block,
+    and its last axis is whole lane rows, so a TPU's default layout of it
+    is that row-major order and no program relayouts it."""
+    R, Dp = pool_packing(head_dim, page_size)
+    return (n_layers, n_pages, n_kv_heads, page_size // R, R * Dp)
+
+
+def pool_geometry(pool, head_dim: int) -> tuple[int, int, int]:
+    """``(R, Dp, page_size)`` of a pool laid out by :func:`pool_shape`."""
+    rows, width = pool.shape[-2:]
+    R = width // head_dim if LANES % head_dim == 0 else 1
+    return R, width // R, rows * R
+
+
+def pack_pages(pages):
+    """``(..., page_size, D)`` positions of whole pages -> ``(..., rows,
+    width)``, the pool's layout (:func:`pool_shape`)."""
+    ps, D = pages.shape[-2:]
+    R, Dp = pool_packing(D, ps)
+    return _pad_lanes(pages, Dp).reshape(*pages.shape[:-2], ps // R, R * Dp)
+
+
+def pack_rows(rows, page_size: int):
+    """Contiguous ``(..., B, Lp, Hk, D)`` rows (``Lp`` a page multiple) ->
+    their ``B * Lp / page_size`` pages in the pool's layout, ``(..., pages,
+    Hk, rows, width)``, row ``b``'s page ``j`` at ``b * Lp / page_size + j``."""
+    *lead, B, Lp, Hk, D = rows.shape
+    pages = rows.reshape(*lead, B * Lp // page_size, page_size, Hk, D)
+    return pack_pages(jnp.swapaxes(pages, -3, -2))
+
+
+def _pad_lanes(x, width: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def unpack_pages(pages, head_dim: int):
+    """Inverse of :func:`pack_pages`: ``(..., rows, width)`` ->
+    ``(..., page_size, head_dim)``."""
+    R, Dp, ps = pool_geometry(pages, head_dim)
+    return pages.reshape(*pages.shape[:-2], ps, Dp)[..., :head_dim]
+
+
+def write_tokens(pool, layer, phys, pos, kv):
+    """Write N tokens' K (or V) into ``pool`` in place: token ``n``, heads
+    ``kv[n]`` (Hk, D), lands in layer ``layer``, physical page ``phys[n]``
+    (>= P drops the write), position ``pos[n]`` of the page.  One
+    dynamic-update-slice per token: unlike a scatter, it asks no other
+    layout of the pool."""
+    R, Dp, _ = pool_geometry(pool, kv.shape[-1])
+    P, Hk = pool.shape[1], pool.shape[2]
+    size = (1, 1, Hk, 1, Dp)
+    kv = _pad_lanes(kv.astype(pool.dtype), Dp)                 # (N, Hk, Dp)
+    for n in range(kv.shape[0]):
+        start = (layer, jnp.minimum(phys[n], P - 1), 0, pos[n] // R,
+                 (pos[n] % R) * Dp)
+        old = jax.lax.dynamic_slice(pool, start, size)
+        new = jnp.where(phys[n] < P, kv[n].reshape(size), old)
+        pool = jax.lax.dynamic_update_slice(pool, new, start)
+    return pool
+
+
+def gather_pages(pool, table, layer, head_dim: int):
+    """The mapped pages of ``layer`` as one contiguous run per row:
+    ``(B, n_pages * page_size, Hk, head_dim)``; sentinels clip to the last
+    page, and the length mask hides them."""
+    P, Hk = pool.shape[1], pool.shape[2]
+    B = table.shape[0]
+    pages = unpack_pages(pool[layer, jnp.clip(table, 0, P - 1)], head_dim)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(B, -1, Hk, head_dim)
+
+
+# ---------------------------------------------------------------------------
 # paged decode attention (one new token vs a block-pooled KV cache)
 # ---------------------------------------------------------------------------
 
-def paged_attention(q, k_pool, v_pool, table, lengths, *,
+def paged_attention(q, k_pool, v_pool, table, lengths, layer, *,
                     backend: Optional[str] = None, interpret: bool = False):
-    """q: (B, 1, H, D); k_pool/v_pool: (P, page, Hk, D); table: (B, n_pages)
-    int32 physical page indices (entries >= P are unmapped sentinels);
-    lengths: (B,) valid KV lengths.
+    """q: (B, 1, H, D); k_pool/v_pool: the stacked pools of L layers laid out
+    by :func:`pool_shape`; table: (B, n_pages) int32 physical page indices
+    (entries >= P are unmapped sentinels); lengths: (B,) valid KV lengths;
+    layer: which layer of the stack to read.
 
     The Pallas kernel walks the block table with scalar-prefetch DMA (no
     contiguous copy ever materializes); the jnp fallback gathers the mapped
     pages into a (B, n_pages*page, Hk, D) view and reuses the flash-decode
-    reduction — same masked-softmax semantics, so the two agree bitwise on
-    the valid positions.
+    reduction — same masked-softmax semantics, so the two agree on the
+    valid positions.
     """
     if _pick(backend) == "pallas":
         from repro.kernels.paged_attention import paged_attention_pallas
 
-        return paged_attention_pallas(q, k_pool, v_pool, table, lengths,
+        return paged_attention_pallas(q, k_pool, v_pool, table, lengths, layer,
                                       interpret=interpret)
-    return _paged_jnp(q, k_pool, v_pool, table, lengths)
+    return _paged_jnp(q, k_pool, v_pool, table, lengths, layer)
 
 
-def _paged_jnp(q, k_pool, v_pool, table, lengths):
-    P, ps = k_pool.shape[0], k_pool.shape[1]
-    B, n_tab = table.shape
-    safe = jnp.clip(table, 0, P - 1)                   # sentinels clip; the
-    k = jnp.take(k_pool, safe, axis=0)                 # length mask hides them
-    v = jnp.take(v_pool, safe, axis=0)                 # (B, n_tab, ps, Hk, D)
-    k = k.reshape(B, n_tab * ps, *k.shape[3:])
-    v = v.reshape(B, n_tab * ps, *v.shape[3:])
-    return _decode_jnp(q, k, v, lengths)
+def _paged_jnp(q, k_pool, v_pool, table, lengths, layer):
+    D = q.shape[-1]
+    return _decode_jnp(q, gather_pages(k_pool, table, layer, D),
+                       gather_pages(v_pool, table, layer, D), lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -253,22 +345,18 @@ def span_attention(q, k, v, base_len, *, backend: Optional[str] = None,
     return jnp.concatenate(outs, axis=1)                       # (B, S, H, D)
 
 
-def paged_span_attention(q, k_pool, v_pool, table, base_len, *,
+def paged_span_attention(q, k_pool, v_pool, table, base_len, layer, *,
                          backend: Optional[str] = None,
                          interpret: bool = False):
     """Paged variant of :func:`span_attention` — q: (B, S, H, D);
-    k_pool/v_pool: (P, page, Hk, D); table: (B, n_pages) int32; base_len: (B,)
-    valid KV length before the span.  One page gather serves all S positions;
-    per-position masking reuses the flash-decode reduction bit-for-bit.
+    k_pool/v_pool, table and layer as in :func:`paged_attention`; base_len:
+    (B,) valid KV length before the span.  One page gather serves all S
+    positions; per-position masking reuses the flash-decode reduction
+    bit-for-bit.
     """
     del backend, interpret
-    P, ps = k_pool.shape[0], k_pool.shape[1]
-    B, n_tab = table.shape
-    safe = jnp.clip(table, 0, P - 1)
-    k = jnp.take(k_pool, safe, axis=0).reshape(B, n_tab * ps,
-                                               *k_pool.shape[2:])
-    v = jnp.take(v_pool, safe, axis=0).reshape(B, n_tab * ps,
-                                               *v_pool.shape[2:])
+    k = gather_pages(k_pool, table, layer, q.shape[-1])
+    v = gather_pages(v_pool, table, layer, q.shape[-1])
     S = q.shape[1]
     outs = [_decode_jnp(q[:, i:i + 1], k, v, base_len + (i + 1))
             for i in range(S)]

@@ -102,12 +102,32 @@ def attention_full(p, cfg: ModelConfig, x, positions, *, causal: bool = True,
     return out, (k, v)
 
 
+def _paged_write(cache, table, positions, k_new, v_new, layer):
+    """Write new K/V ``(B, S, Hk, hd)`` at ``positions`` (B, S) into layer
+    ``layer`` of a paged stack through each slot's block table ``table``
+    (B, n_cols); entries >= P are unmapped and the write drops.  Returns
+    the two pools."""
+    B, S = positions.shape
+    P = cache["k"].shape[1]
+    ps = ops.pool_geometry(cache["k"], k_new.shape[-1])[2]
+    n_cols = table.shape[1]
+    page = positions // ps
+    phys = jnp.where(page < n_cols,
+                     table[jnp.arange(B)[:, None], jnp.minimum(page, n_cols - 1)], P)
+    flat = lambda a: a.reshape(B * S, *a.shape[2:])
+    return tuple(ops.write_tokens(cache[kv], layer, flat(phys), flat(positions % ps),
+                                  flat(new))
+                 for kv, new in (("k", k_new), ("v", v_new)))
+
+
 def attention_decode(p, cfg: ModelConfig, x, cache, *, window: Optional[int] = None,
                      kv_source_cache=None, flags=None):
     """One-token decode.  x: (B, 1, d); cache: {"k","v","len"(B,)}.
 
     With a ring-buffer cache (sliding window) the new KV overwrites slot
-    ``len % window``.  Returns (out, new_cache).
+    ``len % window``.  With ``flags["kv_table"]`` k/v are the paged pools
+    of a whole layer stack (``ops.pool_shape``) and ``flags["kv_layer"]``
+    names this layer.  Returns (out, new_cache).
     """
     B, _, d = x.shape
     H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -133,23 +153,17 @@ def attention_decode(p, cfg: ModelConfig, x, cache, *, window: Optional[int] = N
             k_new = apply_rope(k_new, pos[:, None], cfg)
     table = fl.get("kv_table")
     if table is not None:
-        # Paged KV: cache k/v are a (P, page_size, Hk, hd) block pool shared
-        # by every slot; ``table`` (B, n_cols) maps each slot's logical pages
-        # to physical ones (entries >= P are unmapped — the write drops, the
-        # read masks).  Pages being appended into are private (the engine
-        # CoW-forks shared ones before dispatch), so no two live slots ever
-        # scatter to the same physical location.
+        # Paged KV: cache k/v are the block pools of a whole layer stack,
+        # shared by every slot; this layer writes and reads its own
+        # ``[layer]`` in place.  ``table`` (B, n_cols) maps each slot's
+        # logical pages to physical ones (entries >= P are unmapped — the
+        # write drops, the read masks).  Pages being appended into are
+        # private (the engine CoW-forks shared ones before dispatch), so no
+        # two live slots ever write the same location.
         assert window is None, "paged KV cache supports global attention only"
-        P, ps = cache["k"].shape[0], cache["k"].shape[1]
-        n_cols = table.shape[1]
-        page = pos // ps
-        phys = jnp.where(page < n_cols,
-                         table[jnp.arange(B), jnp.minimum(page, n_cols - 1)], P)
-        k_buf = cache["k"].at[phys, pos % ps].set(
-            k_new[:, 0].astype(cache["k"].dtype), mode="drop")
-        v_buf = cache["v"].at[phys, pos % ps].set(
-            v_new[:, 0].astype(cache["v"].dtype), mode="drop")
-        out = ops.paged_attention(q, k_buf, v_buf, table, pos + 1,
+        layer = fl["kv_layer"]
+        k_buf, v_buf = _paged_write(cache, table, pos[:, None], k_new, v_new, layer)
+        out = ops.paged_attention(q, k_buf, v_buf, table, pos + 1, layer,
                                   backend=fl.get("backend"))
         out = apply_dense(p["o"], out.reshape(B, 1, H * hd))
         return out, {"k": k_buf, "v": v_buf, "len": pos + 1}
@@ -174,8 +188,9 @@ def attention_span(p, cfg: ModelConfig, x, cache, *, flags=None):
     position ``i`` attends causally to ``len + i + 1`` keys — numerically the
     write-then-masked-read order is indistinguishable from S sequential
     :func:`attention_decode` steps (future keys are masked to exact zeros).
-    Global attention only (no ring buffer, no cross).  Returns
-    (out, new_cache) with ``len`` advanced by S.
+    Global attention only (no ring buffer, no cross); a paged cache as in
+    :func:`attention_decode`.  Returns (out, new_cache) with ``len``
+    advanced by S.
     """
     B, S, d = x.shape
     H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -195,18 +210,10 @@ def attention_span(p, cfg: ModelConfig, x, cache, *, flags=None):
             q = apply_rope(q, positions, cfg)
             k_new = apply_rope(k_new, positions, cfg)
     table = fl.get("kv_table")
-    if table is not None:
-        P, ps = cache["k"].shape[0], cache["k"].shape[1]
-        n_cols = table.shape[1]
-        page = positions // ps                          # (B, S)
-        phys = jnp.where(
-            page < n_cols,
-            table[jnp.arange(B)[:, None], jnp.minimum(page, n_cols - 1)], P)
-        k_buf = cache["k"].at[phys, positions % ps].set(
-            k_new.astype(cache["k"].dtype), mode="drop")
-        v_buf = cache["v"].at[phys, positions % ps].set(
-            v_new.astype(cache["v"].dtype), mode="drop")
-        out = ops.paged_span_attention(q, k_buf, v_buf, table, pos,
+    if table is not None:                               # paged, as in attention_decode
+        layer = fl["kv_layer"]
+        k_buf, v_buf = _paged_write(cache, table, positions, k_new, v_new, layer)
+        out = ops.paged_span_attention(q, k_buf, v_buf, table, pos, layer,
                                        backend=fl.get("backend"))
         out = apply_dense(p["o"], out.reshape(B, S, H * hd))
         return out, {"k": k_buf, "v": v_buf, "len": pos + S}

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro.config import MeshConfig, ModelConfig, ShardingConfig
+from repro.kernels.ops import pool_shape
 from repro.models import attention as attn_mod
 from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
@@ -494,30 +495,32 @@ class Model:
                             is_leaf=lambda x: isinstance(x, ParamSpec))
 
     def paged_cache_specs(self, n_pages: int, page_size: int, batch: int) -> dict:
-        """Cache specs for the paged serving layout: each attention layer's
-        K/V become one ``(n_pages, page_size, Hk, hd)`` block pool shared by
-        all slots (the per-slot block tables live host-side in the engine's
-        allocator); ``len`` stays per-slot.  Only decoder-only global-attention
-        stacks qualify — recurrent state and ring buffers have no paged form.
+        """Cache specs for the paged serving layout: each attention layer
+        stack's K/V become one block pool of ``n_pages`` pages shared by all
+        slots, laid out by ``ops.pool_shape`` (the paged kernel's order, so
+        a page of one layer is read where it lies; a ``rem{j}`` layer is a
+        stack of one); the per-slot block tables live host-side in the
+        engine's allocator, and one ``len`` (batch,) serves every layer.
+        Only decoder-only global-attention stacks qualify — recurrent state
+        and ring buffers have no paged form.
         """
         cfg = self.cfg
         assert not cfg.enc_dec and all(k == "attn" for k in cfg.layer_kinds()), \
             "paged KV cache requires a decoder-only global-attention stack"
         dt = jnp.bfloat16 if self.dtype == jnp.bfloat16 else jnp.float32
 
-        def block():
+        def stack(n):
             kv = lambda: ParamSpec(
-                (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dt,
-                ("kv_pages", "kv_page", "kv_heads", "head_dim"))
-            return {"k": kv(), "v": kv(),
-                    "len": ParamSpec((batch,), jnp.int32, ("batch",))}
+                pool_shape(n, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim), dt,
+                ("layers", "kv_pages", "kv_heads", "kv_page", "head_dim"))
+            return {"k": kv(), "v": kv()}
 
-        spec: dict = {}
+        spec: dict = {"len": ParamSpec((batch,), jnp.int32, ("batch",))}
         if self.n_groups > 0:
-            spec["blocks"] = {f"b{i}": _stack_spec(block(), self.n_groups)
+            spec["blocks"] = {f"b{i}": stack(self.n_groups)
                               for i in range(len(self.pattern))}
         for j in range(len(self.rem_kinds)):
-            spec[f"rem{j}"] = block()
+            spec[f"rem{j}"] = stack(1)
         return spec
 
     def init_paged_cache(self, n_pages: int, page_size: int, batch: int) -> dict:
@@ -576,6 +579,38 @@ class Model:
         return logits, cache
 
     # ---------------- decode ----------------
+    def _paged_layers(self, block_fn, params, x, cache, flags):
+        """Run every layer's ``block_fn`` over a paged cache.  Each stacked
+        pool is a carry of the layer scan beside the layer index, so a layer
+        writes its tokens into the whole stack in place and the paged kernel
+        reads the stack at ``[layer]``: no layer's pool is sliced out of the
+        stack or written back.  Every layer reads the one ``len``, which
+        advances by the tokens' count after the last.  Returns (x, new_cache)."""
+        cfg = self.cfg
+        lens = cache["len"]
+
+        def layer_fn(p, kind, h, pools, layer):
+            h, c = block_fn(p, cfg, kind, h, {**pools, "len": lens},
+                            flags={**flags, "kv_layer": layer})
+            return h, {"k": c["k"], "v": c["v"]}
+
+        new_cache: dict = {"len": lens + x.shape[1]}
+        if self.n_groups > 0:
+            def group_body(carry, gp):
+                h, gc, layer = carry
+                gp = _weight_barrier(gp)
+                gc = dict(gc)
+                for i, kind in enumerate(self.pattern):
+                    h, gc[f"b{i}"] = layer_fn(gp[f"b{i}"], kind, h, gc[f"b{i}"], layer)
+                return (h, gc, layer + 1), None
+
+            (x, new_cache["blocks"], _), _ = jax.lax.scan(
+                group_body, (x, cache["blocks"], jnp.int32(0)), params["blocks"])
+        for j, kind in enumerate(self.rem_kinds):
+            x, new_cache[f"rem{j}"] = layer_fn(params[f"rem{j}"], kind, x,
+                                               cache[f"rem{j}"], 0)
+        return x, new_cache
+
     def decode_step(self, params, tokens, cache, table=None):
         """tokens: (B, 1) int32 (or (B, 1, d) embeds).  Returns (logits, cache).
 
@@ -587,7 +622,9 @@ class Model:
         x = self._embed_in(params, tokens)
         flags = {**self._flags(), "moe_exact": True}   # no capacity drops mid-decode
         if table is not None:
-            flags["kv_table"] = table
+            x, new_cache = self._paged_layers(_block_step, params, x, cache,
+                                              {**flags, "kv_table": table})
+            return self._logits(params, x), new_cache
         new_cache: dict = {}
         if self.n_groups > 0:
             def group_body(h, xs):
@@ -621,7 +658,9 @@ class Model:
         x = self._embed_in(params, tokens)
         flags = {**self._flags(), "moe_exact": True}
         if table is not None:
-            flags["kv_table"] = table
+            x, new_cache = self._paged_layers(_block_span, params, x, cache,
+                                              {**flags, "kv_table": table})
+            return self._logits(params, x), new_cache
         new_cache: dict = {}
         if self.n_groups > 0:
             def group_body(h, xs):

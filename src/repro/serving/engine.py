@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.tokenizer import ByteTokenizer
+from repro.kernels import ops
 from repro.models.transformer import Model
 from repro.serving import tracing
 from repro.serving.generation import GenerationConfig
@@ -114,8 +115,10 @@ class ServingEngine:
     wasted lockstep steps on the final block of a stream.
 
     ``paged=True`` swaps the contiguous ``(max_slots, max_len, ...)`` KV
-    pytree for a paged layout: per-layer block *pools* of ``page_size``-token
-    pages plus host-side per-slot block tables (:class:`PagedCacheManager`).
+    pytree for a paged layout: block *pools* of ``page_size``-token pages,
+    one per layer stack in the paged kernel's order (see
+    ``Model.paged_cache_specs``), plus host-side per-slot block tables
+    (:class:`PagedCacheManager`).
     Batched admission prefills the shared batch-prompt prefix once and maps
     every sibling slot's table onto the same physical pages (refcounted);
     a slot gets a private copy only when decode first appends into a shared
@@ -276,37 +279,35 @@ class ServingEngine:
         def _insert_pages(cache, rows, slots, dst_pages):
             """Scatter freshly prefilled rows into the page pools.
 
-            ``rows`` is a prefill cache whose K/V leaves are ``(B, Lp, ...)``
-            with ``Lp`` a page multiple; each row splits into ``Lp/page_size``
-            logical pages and lands at the physical page ``dst_pages[b, j]``
-            (sentinel ≥ n_pages drops the write — padding rows of the
-            admission bucket, and shared prefix pages the owner row already
-            wrote).  ``len`` leaves scatter per slot exactly as in the
-            contiguous ``_insert_many``.
+            ``rows`` is a prefill cache whose K/V leaves are ``(L, B, Lp, Hk,
+            hd)`` (a ``rem{j}`` layer's without the leading L) with ``Lp`` a
+            page multiple; each row splits into ``Lp/page_size`` logical
+            pages, packed into the pool's layout by ``ops.pack_rows`` (the
+            rows move, never the pool), and lands at the physical page
+            ``dst_pages[b, j]`` (sentinel ≥ n_pages drops the write —
+            padding rows of the admission bucket, and shared prefix pages the
+            owner row already wrote).  The rows' length scatters per slot
+            into the cache's one ``len``, as in the contiguous
+            ``_insert_many``.
             """
-            ps = self.page_size
             flat = dst_pages.reshape(-1)
 
-            def ins_axis(axis):
-                def ins(dst, src):
-                    src = src.astype(dst.dtype)
-                    if dst.ndim - axis >= 4:        # K/V pool leaf
-                        lead = src.shape[:axis]
-                        b, lp = src.shape[axis], src.shape[axis + 1]
-                        src_r = src.reshape(*lead, b * (lp // ps), ps,
-                                            *src.shape[axis + 2:])
-                        if axis == 0:
-                            return dst.at[flat].set(src_r, mode="drop")
-                        return dst.at[:, flat].set(src_r, mode="drop")
-                    if axis == 0:                   # per-slot length leaf
-                        return dst.at[slots].set(src, mode="drop")
-                    return dst.at[:, slots].set(src, mode="drop")
-                return ins
+            # every layer of the rows holds the same prompt lengths
+            lens = (rows["blocks"]["b0"]["len"][0] if "blocks" in rows
+                    else rows["rem0"]["len"])
+            out = {"len": cache["len"].at[slots].set(lens, mode="drop")}
 
-            out = {}
+            def ins(dst, src):                      # one K or V stack
+                pages = ops.pack_rows(src.astype(dst.dtype), self.page_size)
+                return dst.at[:, flat].set(pages, mode="drop")
+
             for key, sub in cache.items():
-                axis = 1 if key == "blocks" else 0
-                out[key] = jax.tree.map(ins_axis(axis), sub, rows[key])
+                if key == "blocks":
+                    out[key] = {b: {kv: ins(sub[b][kv], rows[key][b][kv])
+                                    for kv in ("k", "v")} for b in sub}
+                elif key != "len":                  # a stack of one layer
+                    out[key] = {kv: ins(sub[kv], rows[key][kv][None])
+                                for kv in ("k", "v")}
             return out
 
         self._insert_pages = _insert_pages
@@ -317,18 +318,13 @@ class ServingEngine:
             in every layer's pools.  Sentinel entries (the fork list is padded
             to a size bucket) clip their read to the last real page and drop
             their write."""
-            def cp_axis(axis):
-                def cp(leaf):
-                    if leaf.ndim - axis < 4:
-                        return leaf                 # length leaf: no pages
-                    safe = jnp.minimum(src_pages, leaf.shape[axis] - 1)
-                    if axis == 0:
-                        return leaf.at[dst_pages].set(leaf[safe], mode="drop")
-                    return leaf.at[:, dst_pages].set(leaf[:, safe], mode="drop")
-                return cp
+            def cp(leaf):
+                if leaf.ndim < 5:
+                    return leaf                     # length leaf: no pages
+                safe = jnp.minimum(src_pages, leaf.shape[1] - 1)
+                return leaf.at[:, dst_pages].set(leaf[:, safe], mode="drop")
 
-            return {key: jax.tree.map(cp_axis(1 if key == "blocks" else 0), sub)
-                    for key, sub in cache.items()}
+            return jax.tree.map(cp, cache)
 
         self._fork_pages = _fork_pages
 
@@ -374,21 +370,6 @@ class ServingEngine:
                                  top_p)
 
         self._pick_tokens = _pick_tokens
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def _set_lens(cache, lens):
-            # speculative-decode rollback: reset every per-slot KV length
-            # leaf ((..., max_slots) int32) to ``lens`` — pages past the new
-            # length are dropped host-side by the block-table truncation, so
-            # no KV bytes move
-            def fix(leaf):
-                if (leaf.dtype == jnp.int32 and leaf.ndim >= 1
-                        and leaf.shape[-1] == self.max_slots):
-                    return jnp.broadcast_to(lens.astype(jnp.int32), leaf.shape)
-                return leaf
-            return jax.tree.map(fix, cache)
-
-        self._set_lens = _set_lens
 
     # ------------------------------------------------------------------
     def _bucket_len(self, n: int) -> int:

@@ -8,6 +8,7 @@ import pytest
 
 from repro.config import ShardingConfig, get_arch
 from repro.data.tokenizer import ByteTokenizer
+from repro.kernels import ops
 from repro.models.transformer import Model
 from repro.serving.engine import Request, ServingEngine
 
@@ -241,6 +242,26 @@ def test_paged_identical_prompts_fork_on_first_write(tiny, stepwise_outputs,
     assert a.n_forks > 0, "CoW never fired on a shared boundary page"
     assert a.pages_in_use == 0
     a.check(tables=eng.kv.slot_pages)
+
+
+def test_paged_pool_keeps_its_row_major_layout(tiny, eos_id):
+    """The pool is stored as ``ops.pool_shape`` lays it out, the paged
+    kernel's order, with one length per slot, and stays row-major through
+    admission, CoW fork and decode."""
+    model, params = tiny
+    cfg = model.cfg
+    eng = ServingEngine(model, params, max_slots=8, max_len=MAX_LEN,
+                        decode_block=8, eos_id=eos_id, paged=True, page_size=16)
+    assert eng.cache["blocks"]["b0"]["k"].shape == ops.pool_shape(
+        model.n_groups, eng.kv.alloc.n_pages, cfg.n_kv_heads, 16, cfg.head_dim)
+    assert eng.cache["len"].shape == (8,)
+    rs = [Request(rid=i, tokens=TOK.encode(SYS), max_new=6 + i) for i in range(6)]
+    eng.serve(rs)
+    assert eng.kv.alloc.n_forks > 0                  # CoW fork ran
+    for leaf in jax.tree.leaves(eng.cache):
+        assert leaf.format.layout.major_to_minor == tuple(range(leaf.ndim))
+    for kv in ("k", "v"):
+        assert np.any(np.asarray(eng.cache["blocks"]["b0"][kv]) != 0)
 
 
 def test_paged_shared_admission_allocates_prompt_pages_once(tiny):

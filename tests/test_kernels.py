@@ -124,15 +124,30 @@ def test_decode_attention_property(B, S, gq):
 # paged decode attention
 # ---------------------------------------------------------------------------
 
+LAYERS, LAYER = 3, 1      # paged pools are layer stacks; the data sits at LAYER
+
+
+def _stack(pages, layer=LAYER, n_layers=LAYERS):
+    """``(P, page, Hk, D)`` pages -> the stacked pool the kernel reads
+    (``ops.pool_shape``), the pages at ``layer`` and large garbage
+    elsewhere."""
+    pages = np.asarray(ops.pack_pages(jnp.asarray(pages, jnp.float32)
+                                      .transpose(0, 2, 1, 3)))
+    pool = np.full((n_layers, *pages.shape), 1e3, np.float32)
+    pool[layer] = pages
+    return pool
+
+
 def _paginate(k, v, page_size, lengths, n_extra=3):
     """Scatter contiguous ``(B, S, Hk, D)`` K/V into a shuffled page pool.
 
     Returns ``(k_pool, v_pool, table)`` where ``table[b, j]`` is the physical
-    page holding logical positions ``[j*ps, (j+1)*ps)`` of row ``b``.  Table
-    entries for pages entirely past ``lengths[b]`` are the sentinel ``P``
-    (matching the engine's unmapped-column convention), the pool carries
-    ``n_extra`` unreferenced pages, and every out-of-range element is filled
-    with large garbage so any leak through the length mask is loud.
+    page holding logical positions ``[j*ps, (j+1)*ps)`` of row ``b`` in layer
+    ``LAYER`` of a stacked pool.  Table entries for pages entirely past
+    ``lengths[b]`` are the sentinel ``P`` (matching the engine's unmapped-
+    column convention), the pool carries ``n_extra`` unreferenced pages, and
+    every out-of-range element (other layers included) is filled with large
+    garbage so any leak through the length mask is loud.
     """
     B, S, Hk, D = k.shape
     n_tab = -(-S // page_size)
@@ -148,7 +163,7 @@ def _paginate(k, v, page_size, lengths, n_extra=3):
     v_pool[phys.reshape(-1)] = vp.reshape(B * n_tab, page_size, Hk, D)
     table = np.where(np.arange(n_tab)[None] * page_size < np.asarray(lengths)[:, None],
                      phys, P)
-    return (jnp.asarray(k_pool, k.dtype), jnp.asarray(v_pool, v.dtype),
+    return (jnp.asarray(_stack(k_pool), k.dtype), jnp.asarray(_stack(v_pool), v.dtype),
             jnp.asarray(table, jnp.int32))
 
 
@@ -167,7 +182,7 @@ def test_paged_attention_vs_oracle(dtype, page_size, B, S, H, Hk, D):
     k_pool, v_pool, table = _paginate(k, v, page_size, lengths)
     want = ref.decode_attention_ref(q, k, v, jnp.asarray(lengths))
     got = paged_attention_pallas(q, k_pool, v_pool, table, jnp.asarray(lengths),
-                                 interpret=True)
+                                 LAYER, interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
 
@@ -191,7 +206,9 @@ def test_paged_attention_shared_and_forked_pages():
     lengths = jnp.asarray([S, S - 5], jnp.int32)   # forked row mid-page
     q = arr(2, 1, H, D)
     want = ref.decode_attention_ref(q, k, v, lengths)
-    got = paged_attention_pallas(q, k_pool, v_pool, table, lengths, interpret=True)
+    got = paged_attention_pallas(q, jnp.asarray(_stack(k_pool)),
+                                 jnp.asarray(_stack(v_pool)), table, lengths, LAYER,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
@@ -203,9 +220,52 @@ def test_ops_paged_matches_oracle_jnp_fallback():
     lengths = np.asarray([1, 37, 70], np.int32)
     k_pool, v_pool, table = _paginate(k, v, ps, lengths)
     want = ref.decode_attention_ref(q, k, v, jnp.asarray(lengths))
-    got = ops.paged_attention(q, k_pool, v_pool, table, jnp.asarray(lengths),
+    got = ops.paged_attention(q, k_pool, v_pool, table, jnp.asarray(lengths), LAYER,
                               backend="jnp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("D", [64, 80])         # packed two to a row; padded
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_attention_reads_its_layer_of_the_stack(layer, D):
+    """The kernel reads ``[layer]`` of a five-layer stacked pool, laid out
+    as the engine stores it, exactly where the jnp gather does — first,
+    middle and last layer — with sentinel table columns and one physical
+    page shared by both rows."""
+    L, P, ps, H, Hk = 5, 9, 16, 4, 2
+    k_pool, v_pool = (ops.pack_pages(arr(L, P, Hk, ps, D)) for _ in range(2))
+    assert k_pool.shape == ops.pool_shape(L, P, Hk, ps, D)
+    table = jnp.asarray([[3, 7, 1, P], [3, 5, P, P]], jnp.int32)   # page 3 shared
+    lengths = jnp.asarray([3 * ps - 3, ps + 9], jnp.int32)
+    q = arr(2, 1, H, D)
+    want = ops._paged_jnp(q, k_pool, v_pool, table, lengths, layer)
+    got = paged_attention_pallas(q, k_pool, v_pool, table, lengths, layer,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    other = ops._paged_jnp(q, k_pool, v_pool, table, lengths, (layer + 1) % L)
+    assert not np.allclose(np.asarray(got), np.asarray(other), atol=1e-3)
+
+
+@pytest.mark.parametrize("D", [16, 64, 80])     # eight to a row; two; padded
+def test_pool_writes_land_where_reads_find_them(D):
+    """``ops.write_tokens`` puts every position of a slot's pages where
+    ``gather_pages`` (and so the kernel's layout) reads it, in any order;
+    writes to the sentinel page drop, and no other layer or page moves."""
+    L, P, ps, Hk, layer = 3, 6, 16, 2, 1
+    table = np.asarray([[4, 0, 2]], np.int32)
+    n = table.shape[1] * ps
+    kv = arr(n + 2, Hk, D)
+    order = RNG.permutation(n + 2)                 # the last two are dropped
+    phys = np.append(table[0, np.arange(n) // ps], [P, P])[order]
+    pos = (np.arange(n + 2) % ps)[order]
+    pool = jax.jit(ops.write_tokens, static_argnums=1)(
+        jnp.zeros(ops.pool_shape(L, P, Hk, ps, D)), layer, jnp.asarray(phys),
+        jnp.asarray(pos), kv[order])
+    got = ops.gather_pages(pool, jnp.asarray(table), layer, D)[0]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(kv[:n]))
+    untouched = np.ones(pool.shape[:2], bool)
+    untouched[layer, table[0]] = False
+    assert not np.any(np.asarray(pool)[untouched])
 
 
 @settings(max_examples=15, deadline=None)
@@ -219,7 +279,7 @@ def test_paged_attention_property(B, S, page_size, gq):
     k_pool, v_pool, table = _paginate(k, v, page_size, lengths)
     want = ref.decode_attention_ref(q, k, v, jnp.asarray(lengths))
     got = paged_attention_pallas(q, k_pool, v_pool, table, jnp.asarray(lengths),
-                                 interpret=True)
+                                 LAYER, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
 
 
