@@ -32,9 +32,10 @@ def main(argv=None) -> int:
     program_gaps = run.logit_gaps
     control = {}
 
-    def both(arch, served, seed, length, int8=False):
-        control["stats"] = run.gap_stats(program_gaps(arch, served, seed, length, int8=True))
-        gaps = program_gaps(arch, served, seed, length)
+    def both(arch_module, arch, served, seed, length, int8=False):
+        control["stats"] = run.gap_stats(
+            program_gaps(arch_module, arch, served, seed, length, int8=True))
+        gaps = program_gaps(arch_module, arch, served, seed, length)
         control["program"] = run.gap_stats(gaps)
         return gaps
 

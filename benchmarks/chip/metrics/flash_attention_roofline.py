@@ -11,13 +11,14 @@ def read(ctx):
     tr = ctx["trace"]
     if not tr:
         return None
+    a, module = ctx["arch"], ctx["arch_module"]
     flops = nbytes = 0.0
     kernel_ns = 0
     for e in tr.execs["prefill"]:
         if e.record is None or not e.kernel_ns:
             continue
         for n in np.asarray(e.record["lengths"]):
-            f, b = work.flash_attention(ctx["arch"], int(n))
+            f, b = work.flash_attention(a, int(n), module)
             flops += f
             nbytes += b
         kernel_ns += e.kernel_ns

@@ -11,6 +11,7 @@ def read(ctx):
     tr = ctx["trace"]
     if not tr:
         return None
+    a, module = ctx["arch"], ctx["arch_module"]
     flops = nbytes = 0.0
     kernel_ns = 0
     for e in tr.execs["decode"]:
@@ -19,7 +20,7 @@ def read(ctx):
         valid = np.asarray(e.record["valid"])            # (K, slots)
         for slot, context in e.record["context"].items():
             for k in np.nonzero(valid[:, slot])[0]:
-                f, b = work.paged_attention(ctx["arch"], context + int(k))
+                f, b = work.paged_attention(a, context + int(k), module)
                 flops += f
                 nbytes += b
         kernel_ns += e.kernel_ns

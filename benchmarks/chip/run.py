@@ -3,13 +3,15 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (``configs/<name>.json``) and a traffic mix
-(``traffic/<name>.json``) through ``BENCHMARK.json``.  Set-up builds, from the
-program's public constructors, the served path a client sees: ``Model`` at the
-configuration's published widths in bf16 with seeded weights, a paged
-``ServingEngine``, a ``ServedPoolMember``, the modeling stage fitted against
-the calibrated simulator stand-in, and ``Gateway.serve_http`` on loopback.  It
-warms every program shape the mix can reach.  The window then drives streamed
-chat completions from a seeded client in this process for ``--seconds``.
+(``traffic/<name>.json``) through ``BENCHMARK.json``; the configuration names
+its architecture module (``archs/<arch>.py``, see ``reference.py``).  Set-up
+builds, from the program's public constructors, the served path a client sees:
+``Model`` at the configuration's published widths in bf16 with seeded weights,
+a paged ``ServingEngine``, a ``ServedPoolMember``, the modeling stage fitted
+against the calibrated simulator stand-in, and ``Gateway.serve_http`` on
+loopback.  It warms every program shape the mix can reach.  The window then
+drives streamed chat completions from a seeded client in this process for
+``--seconds``.
 
 With ``--trace 0`` the last line of standard output carries the cell's
 end-to-end metrics, with ``--trace 1`` its per-layer metrics (each read by
@@ -39,6 +41,7 @@ import threading  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
+ARCH_DIR = os.path.join(HERE, "archs")
 
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 DRAIN_TIMEOUT_S = 120.0
@@ -64,8 +67,25 @@ def resolve_cell(name: str) -> tuple[dict, dict, dict]:
     cell = cells[name]
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(os.path.join(ROOT, config["file"]))
+    load_arch(cfg, config["file"])           # fails here, naming the file
     mix = load_json(os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
     return dict(cell, bench=bench), cfg, mix
+
+
+def load_arch(cfg: dict, origin: str | None = None):
+    """The architecture module ``archs/<arch>.py`` that the configuration's
+    ``"arch"`` key names.  A missing key or module is a set-up error that
+    names the configuration (``origin``: its file)."""
+    origin = origin or f"configuration {cfg.get('name')!r}"
+    if "arch" not in cfg:
+        raise SystemExit(f"{origin}: no \"arch\" key naming its module in {ARCH_DIR}")
+    path = os.path.join(ARCH_DIR, cfg["arch"] + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"{origin}: arch {cfg['arch']!r} has no module {path}")
+    spec = importlib.util.spec_from_file_location(f"arch_{cfg['arch']}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def set_compile_cache() -> str:
@@ -106,20 +126,6 @@ class CompileCounter:
 
 
 # ----------------------------------------------------------------- building
-def model_config(cfg: dict):
-    from repro.config import ModelConfig
-
-    hf = cfg["hf_config"]
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=hf["num_hidden_layers"],
-        d_model=hf["hidden_size"], n_heads=hf["num_attention_heads"],
-        n_kv_heads=hf["num_key_value_heads"], d_ff=hf["intermediate_size"],
-        vocab_size=hf["vocab_size"], activation="swiglu", norm="rmsnorm",
-        norm_eps=hf["rms_norm_eps"], qkv_bias=True, rope_theta=hf["rope_theta"],
-        tie_embeddings=hf["tie_word_embeddings"], dtype=cfg["serving"]["dtype"],
-        source=cfg["source"])
-
-
 def check_layout(model, abstract) -> None:
     """The program's parameter tree must have the layout the reference reads."""
     import jax
@@ -129,14 +135,14 @@ def check_layout(model, abstract) -> None:
             and all(p.shape == a.shape and p.dtype == a.dtype for p, a in
                     zip(jax.tree.leaves(prog), jax.tree.leaves(abstract))))
     if not same:
-        raise SystemExit("the program's parameter layout differs from reference.abstract_params")
+        raise SystemExit("the program's parameter layout differs from the architecture "
+                         "module's abstract_params")
 
 
 class Cell:
     """Everything set-up builds for one run."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float):
-        import reference
         import spans
         import traffic
         import weights
@@ -151,9 +157,10 @@ class Cell:
 
         self.cfg, self.mix, self.seed = cfg, mix, seed
         serving = cfg["serving"]
-        self.arch = reference.arch(cfg)
-        model = Model(model_config(cfg))
-        abstract = reference.abstract_params(self.arch)
+        self.arch_module = load_arch(cfg)
+        self.arch = self.arch_module.arch(cfg)
+        model = Model(self.arch_module.model_config(cfg))
+        abstract = self.arch_module.abstract_params(self.arch)
         check_layout(model, abstract)
         params = weights.build(abstract, self.arch["d"], self.arch["tied"], seed)
         self.engine = ServingEngine(
@@ -452,14 +459,15 @@ def sample_served(cell: Cell, rec, n_tokens: int, seed: int):
     return out
 
 
-def logit_gaps(arch: dict, served, seed: int, length: int, int8: bool = False) -> list:
+def logit_gaps(arch_module, arch: dict, served, seed: int, length: int,
+               int8: bool = False) -> list:
     """Per served token, how far its reference logit lies below the
     reference's best (with ``int8``: the int8 control's token in its place)."""
     import reference
     import weights
 
-    params = weights.build(reference.abstract_params(arch), arch["d"], arch["tied"], seed)
-    gaps = reference.served_gaps(params, arch, served, length, int8=int8)
+    params = weights.build(arch_module.abstract_params(arch), arch["d"], arch["tied"], seed)
+    gaps = reference.served_gaps(arch_module, params, arch, served, length, int8=int8)
     del params
     return gaps
 
@@ -521,7 +529,7 @@ def run(cell_entry: dict, cfg: dict, mix: dict, seed: int, seconds: float,
 
     if trace:
         ctx = dict(cell=cell, rec=cell.rec, recs=recs, reports=reports, t0=t0,
-                   seconds=seconds, arch=cell.arch, peak=peak,
+                   seconds=seconds, arch=cell.arch, arch_module=cell.arch_module, peak=peak,
                    decode_block=cell.engine.decode_block, trace=None)
         if tracer.error is None and tracer.t_start is not None:
             ctx["trace"] = trace_reduce.reduce(TRACE_DIR, cell.rec, tracer.t_start,
@@ -540,10 +548,10 @@ def run(cell_entry: dict, cfg: dict, mix: dict, seed: int, seconds: float,
     srv.close()
     del cell.engine.params, cell.engine.cache, srv
     cell.member.engine = None
-    arch = cell.arch
+    arch_module, arch = cell.arch_module, cell.arch
     del cell
     gc.collect()
-    gaps = gap_stats(logit_gaps(arch, served, seed, length) if served else [])
+    gaps = gap_stats(logit_gaps(arch_module, arch, served, seed, length) if served else [])
     limits = dict(mix["limits"])
     numbers = dict(checks, logit_gap_mean=gaps["mean"])
     correct = judge(numbers, limits)

@@ -118,8 +118,8 @@ def test_int8_control_in_the_programs_place_is_not_correct(monkeypatch):
     place: the gaps of the tokens it puts first go through ``correct``."""
     gaps = run.logit_gaps
     monkeypatch.setattr(run, "logit_gaps",
-                        lambda arch, served, seed, length, int8=False:
-                        gaps(arch, served, seed, length, int8=True))
+                        lambda arch_module, arch, served, seed, length, int8=False:
+                        gaps(arch_module, arch, served, seed, length, int8=True))
     result = run_tiny(tiny.WIDE_MIX, tiny.WIDE_CONFIG, seconds=8.0)
     assert not result["correct"]
     check = result["checks"]["logit_gap_mean"]

@@ -25,15 +25,16 @@ def config(tied: bool, dtype: str) -> dict:
 @pytest.mark.parametrize("tied", [False, True])
 def test_reference_matches_program_forward_at_f32(tied):
     cfg = config(tied, "float32")
-    a = reference.arch(cfg)
-    model = Model(run.model_config(cfg))
-    abstract = reference.abstract_params(a, jnp.float32)
+    module = run.load_arch(cfg)
+    a = module.arch(cfg)
+    model = Model(module.model_config(cfg))
+    abstract = module.abstract_params(a, jnp.float32)
     run.check_layout(model, abstract)
     params = weights.build(abstract, a["d"], tied, 3)
     toks = np.random.default_rng(0).integers(0, a["vocab"], (2, 40)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         want, _ = model.forward(params, jnp.asarray(toks))
-    got = reference.logits(params, a, [list(t) for t in toks],
+    got = reference.logits(module, params, a, [list(t) for t in toks],
                            [np.arange(40)] * 2, length=64, rows=2)
     np.testing.assert_allclose(np.stack(got), np.asarray(want)[..., : a["vocab"]],
                                rtol=2e-4, atol=2e-4)
@@ -41,9 +42,10 @@ def test_reference_matches_program_forward_at_f32(tied):
 
 def served(cfg: dict, seed: int, n_req: int = 3):
     """Greedy requests served by the program's paged engine in bf16."""
-    a = reference.arch(cfg)
-    model = Model(run.model_config(cfg))
-    params = weights.build(reference.abstract_params(a), a["d"], a["tied"], seed)
+    module = run.load_arch(cfg)
+    a = module.arch(cfg)
+    model = Model(module.model_config(cfg))
+    params = weights.build(module.abstract_params(a), a["d"], a["tied"], seed)
     s = cfg["serving"]
     eng = ServingEngine(model, params, max_slots=s["slots"], max_len=s["max_len"],
                         decode_block=s["decode_block"], paged=True, page_size=s["page_size"])
@@ -52,7 +54,7 @@ def served(cfg: dict, seed: int, n_req: int = 3):
             for i in range(n_req)]
     for r in reqs:
         eng.serve([r])
-    return a, [(r.tokens, r.out_tokens) for r in reqs]
+    return module, a, [(r.tokens, r.out_tokens) for r in reqs]
 
 
 def test_int8_control_departs_more_than_served_bf16():
@@ -63,9 +65,10 @@ def test_int8_control_departs_more_than_served_bf16():
                             vocab_size=2048)
     served_gap, control_gap = [], []
     for seed in (1, 2, 3, 4):
-        a, reqs = served(cfg, seed, n_req=4)
-        params = weights.build(reference.abstract_params(a), a["d"], a["tied"], seed)
-        served_gap.append(max(g.max() for g in reference.served_gaps(params, a, reqs, 128)))
+        module, a, reqs = served(cfg, seed, n_req=4)
+        params = weights.build(module.abstract_params(a), a["d"], a["tied"], seed)
+        served_gap.append(max(g.max() for g in
+                              reference.served_gaps(module, params, a, reqs, 128)))
         control_gap.append(max(g.max() for g in
-                               reference.served_gaps(params, a, reqs, 128, int8=True)))
+                               reference.served_gaps(module, params, a, reqs, 128, int8=True)))
     assert min(control_gap) > max(served_gap), (served_gap, control_gap)

@@ -1,9 +1,14 @@
+from types import SimpleNamespace
+
+import jax
 import numpy as np
 import pytest
 
+import run
+import tiny
 import work
 
-A = dict(d=64, heads=4, kv_heads=2, head_dim=16, ff=96, layers=3, vocab=100)
+A = dict(d=64, heads=4, kv_heads=2, head_dim=16, layers=3, vocab=100, matmul_params=30000)
 
 
 def test_flash_counts_causal_real_tokens():
@@ -26,14 +31,33 @@ def test_paged_counts_live_context_only():
 
 
 def test_model_flops_match_a_count_of_matmuls():
-    p = (A["d"] * A["heads"] * A["head_dim"] + 2 * A["d"] * A["kv_heads"] * A["head_dim"]
-         + A["heads"] * A["head_dim"] * A["d"] + 3 * A["d"] * A["ff"])
-    assert work.matmul_params_per_layer(A) == p
-    assert work.decode_flops(A, 10) == (2 * p * A["layers"] + work.paged_attention(A, 10)[0]
-                                        + 2 * A["d"] * A["vocab"])
-    assert work.prefill_flops(A, 10) == (2 * p * A["layers"] * 10
-                                         + work.flash_attention(A, 10)[0]
-                                         + 2 * A["d"] * A["vocab"])
+    """The qwen2 module's ``matmul_params`` is the size of its layers'
+    matrices, and a token's model flops are 2 a matrix parameter, plus
+    attention and the head."""
+    module = run.load_arch(tiny.CONFIG)
+    a = module.arch(tiny.CONFIG)
+    blocks = module.abstract_params(a)["blocks"]
+    p = sum(int(np.prod(leaf.shape[1:])) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(blocks) if path[-1].key == "w")
+    assert a["matmul_params"] == p
+    assert work.decode_flops(a, 10) == (2 * p * a["layers"] + work.paged_attention(a, 10)[0]
+                                        + 2 * a["d"] * a["vocab"])
+    assert work.prefill_flops(a, 10) == (2 * p * a["layers"] * 10
+                                         + work.flash_attention(a, 10)[0]
+                                         + 2 * a["d"] * a["vocab"])
+
+
+def test_a_modules_own_attention_counts_are_used():
+    own = SimpleNamespace(paged_attention=lambda a, context: (7.0 * context, 1.0),
+                          flash_attention=lambda a, n: (5.0 * n, 2.0))
+    assert work.paged_attention(A, 10, own) == (70.0, 1.0)
+    assert work.flash_attention(A, 10, own) == (50.0, 2.0)
+    head = 2 * A["d"] * A["vocab"]
+    assert work.decode_flops(A, 10, own) == 2 * A["matmul_params"] * A["layers"] + 70.0 + head
+    assert work.prefill_flops(A, 10, own) == (2 * A["matmul_params"] * A["layers"] * 10 + 50.0
+                                              + head)
+    # a module without its own counts takes the generic ones
+    assert work.paged_attention(A, 10, SimpleNamespace()) == work.paged_attention(A, 10)
 
 
 def test_roofline_share_takes_the_binding_bound():

@@ -7,6 +7,7 @@ import traffic
 CONFIG = {
     "name": "tiny",
     "source": "test",
+    "arch": "qwen2",
     "hf_config": {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
                   "num_hidden_layers": 2, "num_key_value_heads": 4, "rms_norm_eps": 1e-6,
                   "rope_theta": 10000.0, "tie_word_embeddings": False, "vocab_size": 512},

@@ -8,7 +8,8 @@ reference takes nothing the program has made.
 
 Each leaf is drawn by a rule on its path:
 
-* norm scales: 1 + 0.1·N(0, 1), so the scale path is exercised;
+* norm scales (a leaf ``scale``, or one named ``*_norm`` such as a per-head
+  ``q_norm``): 1 + 0.1·N(0, 1), so the scale path is exercised;
 * biases: 0.1·N(0, 1), so the QKV bias path is exercised;
 * the token embedding and an untied head: N(0, 1)·2/sqrt(d_model), so the
   logits have a spread of a few units;
@@ -39,7 +40,7 @@ def key_from_seed(seed: int, salt: int = 0) -> jax.Array:
 def _leaf_kind(path) -> str:
     names = [getattr(p, "key", getattr(p, "name", str(p))) for p in path]
     last = names[-1]
-    if last == "scale":
+    if last == "scale" or last.endswith("_norm"):
         return "norm"
     if last == "b":
         return "bias"

@@ -7,6 +7,11 @@ transposes), and prefill attention is causal over the real prompt tokens (not
 the padded bucket).  A fix of either waste therefore raises the share of the
 roofline, and no later change has to correct the count.  bf16 operands: 2 bytes
 per element.
+
+The shapes come from an architecture module's ``arch`` dict (``reference.py``
+lists its keys).  The counts below take that module as ``module`` and use its
+own ``paged_attention`` or ``flash_attention`` where it defines one, for
+attention that the generic formulas do not describe.
 """
 from __future__ import annotations
 
@@ -27,42 +32,44 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def paged_attention(a: dict, context: int) -> tuple[float, float]:
+def paged_attention(a: dict, context: int, module=None) -> tuple[float, float]:
     """One decode token of one sequence attending ``context`` keys, over all
     layers: (flops, bytes).  QK^T and PV are 2·H·D flops per key each; bytes
-    are the K and V of the live context plus q and o."""
+    are the K and V of the live context plus q and o.  The architecture
+    ``module``'s own count where it defines ``paged_attention``."""
+    if hasattr(module, "paged_attention"):
+        return module.paged_attention(a, context)
     H, Hk, D, L = a["heads"], a["kv_heads"], a["head_dim"], a["layers"]
     flops = 4.0 * H * D * context
     nbytes = (2.0 * context * Hk * D + 2.0 * H * D) * BYTES
     return L * flops, L * nbytes
 
 
-def flash_attention(a: dict, n: int) -> tuple[float, float]:
+def flash_attention(a: dict, n: int, module=None) -> tuple[float, float]:
     """Causal self-attention over ``n`` real prompt tokens, all layers:
     (flops, bytes).  Query i sees i+1 keys: 4·H·D·n(n+1)/2 flops; bytes are
-    q, k, v and o of the real tokens."""
+    q, k, v and o of the real tokens.  The architecture ``module``'s own
+    count where it defines ``flash_attention``."""
+    if hasattr(module, "flash_attention"):
+        return module.flash_attention(a, n)
     H, Hk, D, L = a["heads"], a["kv_heads"], a["head_dim"], a["layers"]
     flops = 2.0 * H * D * n * (n + 1)
     nbytes = (2.0 * n * H * D + 2.0 * n * Hk * D) * BYTES
     return L * flops, L * nbytes
 
 
-def matmul_params_per_layer(a: dict) -> int:
-    d, ff = a["d"], a["ff"]
-    hq, hk = a["heads"] * a["head_dim"], a["kv_heads"] * a["head_dim"]
-    return d * hq + 2 * d * hk + hq * d + 3 * d * ff
+def prefill_flops(a: dict, n: int, module=None) -> float:
+    """Model flops to prefill ``n`` real tokens and score the last one: 2 per
+    matrix parameter a token runs through (``a["matmul_params"]`` a layer),
+    attention, and the head."""
+    dense = 2.0 * a["matmul_params"] * a["layers"] * n
+    return dense + flash_attention(a, n, module)[0] + 2.0 * a["d"] * a["vocab"]
 
 
-def prefill_flops(a: dict, n: int) -> float:
-    """Model flops to prefill ``n`` real tokens and score the last one."""
-    dense = 2.0 * matmul_params_per_layer(a) * a["layers"] * n
-    return dense + flash_attention(a, n)[0] + 2.0 * a["d"] * a["vocab"]
-
-
-def decode_flops(a: dict, context: int) -> float:
+def decode_flops(a: dict, context: int, module=None) -> float:
     """Model flops of one decode token attending ``context`` keys."""
-    dense = 2.0 * matmul_params_per_layer(a) * a["layers"]
-    return dense + paged_attention(a, context)[0] + 2.0 * a["d"] * a["vocab"]
+    dense = 2.0 * a["matmul_params"] * a["layers"]
+    return dense + paged_attention(a, context, module)[0] + 2.0 * a["d"] * a["vocab"]
 
 
 def roofline_share(flops: float, nbytes: float, seconds: float, peak: dict) -> float:
